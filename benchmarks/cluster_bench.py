@@ -549,4 +549,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    print(f"compile cache: {use_compile_cache()}")
     main()

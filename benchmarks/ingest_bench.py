@@ -6,7 +6,7 @@ m=2^26, measured per backend of :mod:`repro.index.ingest`:
 
 * ``per_read_loop`` — the seed semantics: one jit'd full-array
   ``bf.at[locs].set(1)`` copy per read;
-* ``jnp``        — ONE jit-compiled, donated, sort-dedup'd scatter for the
+* ``jnp``        — ONE jit-compiled, donated, in-place scatter-OR for the
   whole batch (the single body that replaced the three packed.py scatters);
 * ``idl_insert`` — the planned backend: host sort/dedup/run-length planner
   + the generalized run-coalesced ``insert_runs`` executor (the Pallas
@@ -203,4 +203,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    print(f"compile cache: {use_compile_cache()}")
     main()

@@ -1,7 +1,7 @@
 """Batched donated insert vs the seed's per-read loop.
 
 Measures the original acceptance-criterion path: 64 reads inserted into a
-partitioned IDL-BF as ONE jit-compiled, donated, dedup'd scatter (the
+partitioned IDL-BF as ONE jit-compiled, donated scatter-OR (the
 ``jnp`` backend of `repro.index.ingest`) against the seed semantics of one
 `bf.at[locs].set(1)` full-array copy per read. See ``ingest_bench.py`` for
 the full per-backend ingest matrix.
@@ -63,4 +63,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    print(f"compile cache: {use_compile_cache()}")
     main()

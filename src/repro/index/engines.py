@@ -26,7 +26,7 @@ every engine's storage as a packed ``(n_rows, W)`` bit-matrix:
   Pallas ``probe_rows`` kernel), ``"sharded"`` (``shard_map`` over a 1-D
   device mesh);
 * inserts through :mod:`repro.index.ingest` — backends ``"jnp"`` (one
-  donated sort-dedup scatter), ``"idl_insert"`` (host run planner + the
+  donated in-place scatter-OR), ``"idl_insert"`` (host run planner + the
   Pallas ``insert_runs`` kernel, one launch per batch), ``"sharded"``
   (device-local scatters, no collectives).
 

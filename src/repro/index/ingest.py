@@ -21,9 +21,9 @@ shape, matrix geometry, the run-coalescing tile height — and is built once
 per ``(cfg, scheme, read_shape, matrix_shape)`` through an LRU cache
 (:func:`plan_insert`). Executing a plan picks one of three backends:
 
-* ``"jnp"``        — one jit-compiled, donated, sort-deduplicated scatter
-  for the whole batch (the reference; the single implementation that
-  replaced the three divergent scatter bodies in ``packed.py``);
+* ``"jnp"``        — one jit-compiled, donated scatter-OR for the whole
+  batch (the reference; the single implementation that replaced the
+  three divergent scatter bodies in ``packed.py``);
 * ``"idl_insert"`` — the host-side run-length planner + the generalized
   Pallas ``insert_runs`` kernel: the batch's targets are sorted,
   deduplicated and run-length-encoded by matrix row-block, each touched
@@ -53,7 +53,6 @@ from typing import Iterable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import hashing, idl as idl_mod, minhash
@@ -286,8 +285,8 @@ def plan_insert(
 
     ``rows_per_block`` defaults to the IDL locality window ``cfg.L``
     translated to matrix rows (``L/32`` packed words for ``"bits"``; for
-    row/column targets, ``L`` rows clamped so one tile's f32 bit image
-    stays VMEM-friendly), as a power of two that divides ``n_rows``.
+    row/column targets, ``L`` rows clamped like the query planner's), as
+    a power of two that divides ``n_rows``.
     ``inserts_per_run`` defaults to the TPU lane width (128); 32 on a CPU
     host, where narrow runs waste fewer pad lanes.
     """
@@ -300,7 +299,7 @@ def plan_insert(
         if kind == "bits":
             target = max(cfg.L // 32, 1)
         else:
-            # keep one DMA tile's unpacked f32 bit image ~<= 2 MB
+            # the query planner's bound (see query.plan_query)
             target = max(1, min(cfg.L, (1 << 21) // max(row_words * 128, 1)))
         rows_per_block = query._pow2_block(n_rows, target)
     if n_rows % rows_per_block:
@@ -334,8 +333,8 @@ def _execute_jnp(matrix, reads, aux, *, plan: InsertPlan):
     shape = matrix.shape
     row, wc, bit = plan.targets(reads, aux)
     if plan.kind == "bits":
-        # W == 1: the flat location is one sort key — skip the 3-key
-        # lexsort (masked rows land out of range and are dropped)
+        # W == 1: scatter into the flat word vector itself (masked rows
+        # land out of range and are dropped)
         flat = (row.astype(jnp.uint32) << jnp.uint32(5)) | bit
         words = packed.scatter_or(jnp.reshape(matrix, (-1,)), flat)
         return words.reshape(shape)
@@ -382,12 +381,12 @@ def _sharded_inserter(plan: InsertPlan, mesh: Mesh):
             mat = jnp.pad(
                 mat, ((0, pad), (0, 0)) if split_rows else ((0, 0), (0, pad)))
         if aux_spec is None:
-            out = shard_map(
+            out = jax.shard_map(
                 lambda m, r: body(m, r, None), mesh=mesh,
                 in_specs=(mat_spec, P()), out_specs=mat_spec,
             )(mat, reads)
         else:
-            out = shard_map(
+            out = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(mat_spec, P(), aux_spec), out_specs=mat_spec,
             )(mat, reads, aux)

@@ -1,4 +1,4 @@
-"""Packed-uint32 word storage + the ONE dedup'd scatter-OR primitive.
+"""Packed-uint32 word storage + the ONE scatter-OR primitive.
 
 Canonical storage for every engine behind the :class:`~repro.index.protocol.
 GeneIndex` protocol: Bloom-filter bits live packed 32-per-``uint32`` word
@@ -9,20 +9,20 @@ Since the ingest refactor all mutation flows through
 :mod:`repro.index.ingest` (the shared ``InsertPlan`` layer), and the three
 storage-specific scatter bodies this module used to carry (flat words,
 bit-sliced, RAMBO rows) collapsed into one: :func:`scatter_or_matrix`, a
-sort-deduplicated scatter-OR of single bits at ``(row, word_col, bit)``
-targets of any packed ``(n_rows, W)`` matrix —
-
-1. targets are ``lexsort``-ed and duplicates removed with a neighbour
-   compare (no ``jnp.unique``, whose output shape is data-dependent and
-   would break jit); duplicates are routed to an out-of-range row and
-   dropped by the ``mode="drop"`` scatter;
-2. the deduped bits are scatter-added into a zero delta (safe: each
-   (row, word, bit) appears at most once, so add == or) and OR-ed into
-   the destination.
+scatter-OR of single bits at ``(row, word_col, bit)`` targets of any
+packed ``(n_rows, W)`` matrix, in 32 passes, one per bit position ``j``:
+the targets of bit ``j`` gather their words and write back
+``word | 1 << j``. All targets of one word in one pass write the same
+value, so duplicate targets need no dedup, and no sort: on a TPU a sort
+of a batch's ~2^18 targets takes minutes to compile, three keys longer.
+Out-of-range targets (masked ones are routed to ``row == n_rows``) are
+dropped. The destination is donated by every caller, so the passes
+update it in place: no second matrix-sized buffer, which an 8 GiB state
+on a 16 GB chip has no room for.
 
 The old per-layout helpers remain: ``scatter_or_bitsliced`` and
 ``scatter_or_rows`` as thin views of the one body, ``scatter_or`` as its
-W == 1 single-sort-key specialization (the flat-BF fast path). The legacy
+W == 1 specialization over flat bit locations (the flat-BF path). The legacy
 jit entry points (``insert_batch_words`` / ``insert_batch_bitsliced`` /
 ``insert_batch_rows``) finished their deprecation window and are now
 call-time ``ImportError`` stubs pointing at ``ingest.InsertPlan``.
@@ -47,21 +47,21 @@ def batch_locations(
 
 
 # ---------------------------------------------------------------------------
-# The one dedup'd scatter-OR body (pure jnp, jit/vmap safe).
+# The one scatter-OR body (pure jnp, jit/vmap safe).
 # ---------------------------------------------------------------------------
 
-def _mask_duplicates(sort_key_rows, primary: jax.Array, oob) -> jax.Array:
-    """Return ``primary`` with duplicate entries replaced by ``oob``.
+def _set_bits(matrix: jax.Array, index: tuple, bits: jax.Array) -> jax.Array:
+    """OR bit ``bits[i]`` into ``matrix[index][i]``, one pass per bit
+    position (see the module docstring); ``index[0]`` out of range drops
+    a target."""
+    oob = matrix.shape[0]
 
-    ``sort_key_rows``: tuple-like (k, P) stack of already-sorted key rows;
-    an entry is a duplicate iff every key row equals its left neighbour.
-    """
-    same = jnp.ones(primary.shape, dtype=bool)
-    for row in sort_key_rows:
-        same = same & jnp.concatenate(
-            [jnp.zeros((1,), dtype=bool), row[1:] == row[:-1]]
-        )
-    return jnp.where(same, oob, primary)
+    def one_bit(j, m):
+        idx = (jnp.where(bits == j, index[0], oob),) + index[1:]
+        old = m.at[idx].get(mode="fill", fill_value=0)
+        return m.at[idx].set(old | (jnp.uint32(1) << j), mode="drop")
+
+    return jax.lax.fori_loop(jnp.uint32(0), jnp.uint32(32), one_bit, matrix)
 
 
 def scatter_or_matrix(
@@ -72,36 +72,26 @@ def scatter_or_matrix(
 ) -> jax.Array:
     """OR bit ``bits[i]`` of word ``(rows[i], word_cols[i])`` into ``matrix``.
 
-    One lexsort + one scatter for the whole target stream, duplicate-safe;
-    out-of-range targets (including deliberately masked ones routed to
-    ``row == n_rows``) are dropped. This is the single scatter body behind
-    every engine's insert path.
+    Duplicate-safe; out-of-range targets (including deliberately masked
+    ones routed to ``row == n_rows``) are dropped. This is the single
+    scatter body behind every engine's insert path.
     """
-    r = rows.reshape(-1).astype(jnp.int32)
-    c = word_cols.reshape(-1).astype(jnp.int32)
-    b = bits.reshape(-1).astype(jnp.uint32)
-    order = jnp.lexsort((b, c, r))
-    r, c, b = r[order], c[order], b[order]
-    r = _mask_duplicates((r, c, b), r, matrix.shape[0])
-    delta = jnp.zeros_like(matrix).at[r, c].add(
-        jnp.uint32(1) << b, mode="drop")
-    return matrix | delta
+    return _set_bits(
+        matrix,
+        (rows.reshape(-1).astype(jnp.int32),
+         word_cols.reshape(-1).astype(jnp.int32)),
+        bits.reshape(-1).astype(jnp.uint32))
 
 
 def scatter_or(words: jax.Array, locs: jax.Array) -> jax.Array:
     """OR the bits at flat bit-locations ``locs`` into packed ``words``.
 
-    The W == 1 specialization of :func:`scatter_or_matrix`: flat bit
-    locations are one key, so a single ``sort`` replaces the 3-key
-    ``lexsort`` (the fast path every flat-BF insert takes). Out-of-range
+    The W == 1 specialization of :func:`scatter_or_matrix`. Out-of-range
     locations are dropped.
     """
-    flat = jnp.sort(locs.reshape(-1).astype(jnp.uint32))
-    word_idx = (flat >> jnp.uint32(5)).astype(jnp.int32)
-    word_idx = _mask_duplicates((flat,), word_idx, words.shape[0])
-    bit = jnp.uint32(1) << (flat & jnp.uint32(31))
-    delta = jnp.zeros_like(words).at[word_idx].add(bit, mode="drop")
-    return words | delta
+    flat = locs.reshape(-1).astype(jnp.uint32)
+    return _set_bits(words, ((flat >> jnp.uint32(5)).astype(jnp.int32),),
+                     flat & jnp.uint32(31))
 
 
 def scatter_or_bitsliced(

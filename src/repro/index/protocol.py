@@ -7,7 +7,7 @@ RAMBO, the bit-sliced serving index) speaks the same four-method protocol:
 * ``insert_batch(reads, file_ids)``    — index a ``(B, read_len)`` batch of
   base-code reads. Every engine routes through the shared ingest layer
   (:mod:`repro.index.ingest`): ``backend="jnp"`` is one jit-compiled,
-  donated, dedup'd scatter (no per-read Python loop),
+  donated, in-place scatter-OR (no per-read Python loop),
   ``backend="idl_insert"`` the host run-length planner + generalized
   Pallas ``insert_runs`` kernel (one launch per batch),
   ``backend="sharded"`` a collective-free ``shard_map`` over a 1-D device

@@ -64,7 +64,6 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import idl as idl_mod
@@ -393,8 +392,8 @@ def plan_query(
 
     ``rows_per_block`` defaults to the IDL locality window ``cfg.L``
     translated to matrix rows (``L/32`` packed words for bit probes, ``L``
-    rows for row probes), clamped to a VMEM-friendly power of two that
-    divides ``n_rows``. ``probes_per_run`` defaults to the TPU lane width
+    rows for row probes), clamped to a power of two that divides
+    ``n_rows``. ``probes_per_run`` defaults to the TPU lane width
     (128); on a CPU host 32 — narrower runs waste fewer pad lanes where
     there is no vector unit to fill.
     """
@@ -405,7 +404,10 @@ def plan_query(
         if bit_probe:
             target = max(cfg.L // 32, 1)
         else:
-            # keep one DMA tile's unpacked bit image ~<= 2 MB of f32
+            # 2^21 / (W*128) rows: the bound from when the kernel unpacked
+            # a tile into a 2 MB f32 bit image, kept so plans and locality
+            # counters stay comparable (whether the tile should follow L
+            # instead is open: PERF.md)
             target = max(8, min(cfg.L, (1 << 21) // max(row_words * 128, 1)))
         rows_per_block = _pow2_block(n_rows, target)
     if n_rows % rows_per_block:
@@ -513,7 +515,7 @@ def _sharded_executor(plan: QueryPlan, mesh: Mesh):
             matrix = jnp.reshape(matrix, plan.matrix_shape)
             if pad:
                 matrix = jnp.pad(matrix, ((0, pad), (0, 0)))
-            miss = shard_map(
+            miss = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(MESH_AXIS, None), P()), out_specs=P(),
             )(matrix, reads)
@@ -537,7 +539,7 @@ def _sharded_executor(plan: QueryPlan, mesh: Mesh):
         matrix = jnp.reshape(matrix, plan.matrix_shape)
         if pad:
             matrix = jnp.pad(matrix, ((0, 0), (0, pad)))
-        out = shard_map(
+        out = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(None, MESH_AXIS), P()),
             out_specs=P(None, None, MESH_AXIS),

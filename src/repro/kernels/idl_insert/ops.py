@@ -14,8 +14,9 @@ Two generations of planner (mirroring kernel.py):
   launch. Sorting makes runs of a block consecutive, so the kernel
   accumulates into a resident output tile (revisiting) and each touched
   block costs exactly one tile read + one tile write, however many runs
-  land in it. IDL needs few blocks (locality!) → few tiles; RH touches
-  ~every block once → many singleton tiles. The run/tile structure is
+  land in it; the kernel writes the tiles back into the donated matrix.
+  IDL needs few blocks (locality!) → few tiles; RH touches ~every block
+  once → many singleton tiles. The run/tile structure is
   itself a locality measurement.
 """
 
@@ -28,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import lanes
 from repro.kernels.idl_insert import kernel, ref
 
 
@@ -79,31 +81,20 @@ def plan_insert_rounds(
     )
 
 
-_PAD_BLOCK = np.int32(np.iinfo(np.int32).max)  # never a real block id
-
-
 @dataclasses.dataclass
 class InsertRunPlan:
     """One-launch, sorted-run plan over a flattened (rows*W*32)-bit space."""
 
     block_ids: np.ndarray    # (R_pad,) int32 row-block per run, nondecreasing
-    slot_ids: np.ndarray     # (R_pad,) int32 output tile slot, nondecreasing
     offsets: np.ndarray      # (R_pad, C) int32 tile bit offsets, -1 padded
     run_lengths: np.ndarray  # (n_runs,) int32 inserts per true run
                              # (precomputed at plan time so telemetry never
                              # re-reduces the (R_pad, C) offset matrix)
-    uniq_blocks: np.ndarray  # (S_pad,) int32 touched blocks, sorted unique,
-                             # padded with _PAD_BLOCK (dropped at write-back)
     n_locs: int              # deduplicated insert count
     n_runs: int              # true run count (before pow2 padding)
-    n_tiles: int             # true touched-block count (before pow2 padding)
+    n_tiles: int             # touched-block count
     block_bits: int          # bits per tile (rows_per_block * W * 32)
     inserts_per_run: int
-
-    @property
-    def n_slots(self) -> int:
-        """Pow2-padded output tile count (the executor's static shape)."""
-        return int(self.uniq_blocks.shape[0])
 
     @property
     def dma_bytes(self) -> int:
@@ -121,11 +112,9 @@ def plan_insert_runs(
     so arbitrarily large matrices are fine. Negative positions are dropped
     (masked inserts). Returns None when nothing survives.
 
-    Both data-dependent sizes are padded to powers of two so the
-    executor's compile cache stays small: the run count (pad runs are
-    all-pad lanes of the last block/slot — bit-exact no-ops) and the
-    output tile count (pad slots carry the ``_PAD_BLOCK`` sentinel and
-    are dropped by the write-back scatter).
+    The run count is padded to a power of two, and at least 8, so the
+    executor's compile cache stays small: pad runs are all-pad lanes of
+    the last block — bit-exact no-ops that move no tile.
     """
     flat = np.asarray(flat_bits, dtype=np.int64).reshape(-1)
     flat = np.unique(flat[flat >= 0])        # sorted + deduplicated
@@ -140,42 +129,34 @@ def plan_insert_runs(
     np.not_equal(blocks[1:], blocks[:-1], out=start[1:])
     pos_in_block = idx - np.maximum.accumulate(np.where(start, idx, 0))
     # new run at a block start or every C inserts (split long runs); block
-    # keys are nondecreasing so a cumsum numbers runs and slots directly
+    # keys are nondecreasing so a cumsum numbers runs directly
     run = np.cumsum(start | (pos_in_block % c == 0)) - 1
-    slot = np.cumsum(start) - 1
     n_runs = int(run[-1]) + 1
-    r_pad = 1 << max(n_runs - 1, 1).bit_length()
+    r_pad = max(1 << (n_runs - 1).bit_length(), lanes.RUNS_PER_BLOCK)
     pos = pos_in_block % c
 
     offs = np.full((r_pad, c), -1, dtype=np.int32)
     offs[run, pos] = (flat % block_bits).astype(np.int32)
-    uniq = blocks[start].astype(np.int32)
-    bids = np.full(r_pad, uniq[-1], dtype=np.int32)
+    bids = np.full(r_pad, blocks[-1], dtype=np.int32)
     bids[run] = blocks.astype(np.int32)
-    sids = np.full(r_pad, len(uniq) - 1, dtype=np.int32)
-    sids[run] = slot.astype(np.int32)
-    n_tiles = len(uniq)
-    s_pad = 1 << max(n_tiles - 1, 1).bit_length()
-    uniq = np.concatenate(
-        [uniq, np.full(s_pad - n_tiles, _PAD_BLOCK, dtype=np.int32)])
 
     return InsertRunPlan(
-        block_ids=bids, slot_ids=sids, offsets=offs, uniq_blocks=uniq,
+        block_ids=bids, offsets=offs,
         run_lengths=np.bincount(run, minlength=n_runs).astype(np.int32),
-        n_locs=n, n_runs=n_runs, n_tiles=n_tiles,
+        n_locs=n, n_runs=n_runs, n_tiles=int(start.sum()),
         block_bits=block_bits, inserts_per_run=c,
     )
 
 
 def insert_planned(
     matrix: jax.Array, plan: InsertRunPlan | None, *,
-    interpret: bool = True, use_ref: bool = False,
+    interpret: bool, use_ref: bool = False,
 ) -> jax.Array:
     """Execute a run plan against a packed (n_rows, W) matrix — ONE launch.
 
-    The matrix buffer is donated: on accelerators the tile write-back is
-    in-place. ``use_ref`` swaps the Pallas kernel for its fused jnp oracle
-    (same plan, bit-identical — the executor on hosts without Mosaic).
+    The matrix buffer is donated and the kernel updates it in place.
+    ``use_ref`` swaps the Pallas kernel for its jnp oracle (same plan,
+    bit-identical — the executor on hosts without Mosaic).
     """
     if plan is None:
         return matrix
@@ -184,12 +165,9 @@ def insert_planned(
         raise ValueError(
             f"block_bits={plan.block_bits} not a row multiple of W={w}")
     return _planned_insert(
-        matrix,
-        jnp.asarray(plan.block_ids), jnp.asarray(plan.slot_ids),
-        jnp.asarray(plan.offsets), jnp.asarray(plan.uniq_blocks),
+        matrix, jnp.asarray(plan.block_ids), jnp.asarray(plan.offsets),
         rows_per_block=plan.block_bits // (w * 32),
         inserts_per_run=plan.inserts_per_run,
-        n_tiles=plan.n_slots,
         row_words=w,
         interpret=interpret,
         use_ref=use_ref,
@@ -199,33 +177,35 @@ def insert_planned(
 @functools.partial(
     jax.jit,
     donate_argnums=(0,),
-    static_argnames=("rows_per_block", "inserts_per_run", "n_tiles",
-                     "row_words", "interpret", "use_ref"),
+    static_argnames=("rows_per_block", "inserts_per_run", "row_words",
+                     "interpret", "use_ref"),
 )
-def _planned_insert(matrix, bids, sids, offs, uniq, *, rows_per_block,
-                    inserts_per_run, n_tiles, row_words, interpret, use_ref):
-    """One fused call: run the kernel (or ref) over all runs, then scatter
-    the updated tiles back (slots are unique blocks — conflict-free)."""
+def _planned_insert(matrix, bids, offs, *, rows_per_block, inserts_per_run,
+                    row_words, interpret, use_ref):
+    """One fused call: run the kernel (or ref) over all runs. The kernel
+    takes the transposed view (see ``repro.kernels.lanes``) and splits
+    each tile bit offset into its row and its bit column."""
     shape = matrix.shape
     matrix = jnp.reshape(matrix, (-1, row_words))
     if use_ref:
-        tiles = ref.insert_runs_ref(
-            matrix, bids, sids, offs,
-            rows_per_block=rows_per_block, n_tiles=n_tiles,
-        )
+        out = ref.insert_runs_ref(matrix, bids, offs,
+                                  rows_per_block=rows_per_block)
     else:
-        tiles = kernel.insert_runs(
-            matrix, bids, sids, offs,
+        row_bits = row_words * 32
+        mine = offs >= 0
+        out = kernel.insert_runs(
+            matrix.T, bids,
+            jnp.where(mine, offs // row_bits, -1),
+            jnp.where(mine, offs % row_bits, 0),
             rows_per_block=rows_per_block,
             inserts_per_run=inserts_per_run,
-            n_tiles=n_tiles,
             interpret=interpret,
-        )
-    return ref.apply_tiles_to_matrix(matrix, uniq, tiles).reshape(shape)
+        ).T
+    return out.reshape(shape)
 
 
 def insert_with_plan(
-    bf_words: jax.Array, plan: InsertPlan, *, interpret: bool = True,
+    bf_words: jax.Array, plan: InsertPlan, *, interpret: bool,
     use_ref: bool = False,
 ) -> jax.Array:
     block_words = plan.block_bits // 32

@@ -51,51 +51,28 @@ def apply_insert_to_words(
 def insert_runs_ref(
     matrix: jax.Array,
     block_ids: jax.Array,
-    slot_ids: jax.Array,
     offsets: jax.Array,
     *,
     rows_per_block: int,
-    n_tiles: int,
 ) -> jax.Array:
-    """(n_tiles, rows_per_block, W) updated tiles — oracle for insert_runs.
+    """The updated ``(n_rows, W)`` matrix — oracle for insert_runs.
 
     Relies on the planner's invariant that deduplicated runs never repeat a
-    (tile, offset) pair, so accumulating each lane's single-bit word into
-    its slot-local word with a scatter-add equals the OR the kernel
-    computes (add == OR on disjoint bits). Memory is exactly the touched
-    tiles, so this doubles as the CPU executor for production-size plans.
-    """
-    w = matrix.shape[1]
-    nw = rows_per_block * w
-    valid = offsets >= 0
-    off = jnp.where(valid, offsets, 0)
-    slot_word = slot_ids[:, None] * nw + (off >> 5)    # (R, C) flat word
-    bit = jnp.where(
-        valid, jnp.uint32(1) << (off & 31).astype(jnp.uint32), np.uint32(0))
-    acc = jnp.zeros((n_tiles * nw,), dtype=jnp.uint32)
-    acc = acc.at[slot_word.reshape(-1)].add(bit.reshape(-1), mode="drop")
-    # base tile per slot: every run of a slot names the same block, so a
-    # scatter-max of run block ids recovers the slot -> block map in-graph
-    slot_block = jnp.zeros((n_tiles,), dtype=jnp.int32).at[slot_ids].max(
-        block_ids, mode="drop")
-    base = matrix.reshape(-1, nw)[slot_block]          # (S, NW)
-    return (base | acc.reshape(n_tiles, nw)).reshape(
-        n_tiles, rows_per_block, w)
-
-
-def apply_tiles_to_matrix(
-    matrix: jax.Array, uniq_blocks: jax.Array, tiles: jax.Array
-) -> jax.Array:
-    """Scatter updated (S_pad, RPB, W) tiles back.
-
-    Real block ids are unique per plan (conflict-free); pad slots carry an
-    out-of-range sentinel block and their (never-written) tiles are
-    dropped by the scatter.
+    (tile, offset) pair, so scatter-adding each target's bit where it is
+    not yet set equals the OR the kernel computes. The scatter updates the
+    (donated) matrix in place, so this doubles as the CPU executor for
+    production-size plans.
     """
     n_rows, w = matrix.shape
-    rpb = tiles.shape[1]
-    blocks = matrix.reshape(-1, rpb, w)
-    return blocks.at[uniq_blocks].set(tiles, mode="drop").reshape(n_rows, w)
+    valid = offsets >= 0
+    word = jnp.where(valid, offsets, 0) >> 5           # word within tile
+    row = jnp.where(valid, block_ids[:, None] * rows_per_block + word // w,
+                    n_rows)                            # pad lanes dropped
+    col = word % w
+    bit = jnp.uint32(1) << (offsets & 31).astype(jnp.uint32)
+    old = matrix.at[row, col].get(mode="fill", fill_value=0)
+    return matrix.at[row, col].add(
+        jnp.where((old & bit) != 0, jnp.uint32(0), bit), mode="drop")
 
 
 def insert_locations_packed_ref(bf_words: jax.Array, locs: jax.Array) -> jax.Array:

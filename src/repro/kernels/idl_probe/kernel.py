@@ -1,21 +1,23 @@
-"""Pallas TPU kernel: block-resident Bloom-filter probe.
+"""Pallas TPU kernels: block-resident Bloom-filter probes.
 
 The TPU translation of the paper's cache-locality argument (DESIGN.md §2):
 
 * The BF lives packed (uint32 words) in HBM — far too big for VMEM.
 * The host scheduler (ops.plan_probe_runs) run-length-encodes the probe
-  stream by BF *block* (block = the IDL locality window L). IDL makes runs
-  long (mean ≈ 1/(1−J) kmers); RH makes every probe its own run.
+  stream by block. IDL makes runs long when the block covers the locality
+  window; RH makes every probe its own run.
 * Grid = one step per run. A scalar-prefetch array holds each run's block
-  id; the BlockSpec index_map consumes it, so Pallas DMAs exactly ONE
-  L-sized BF tile from HBM per run and double-buffers the next tile while
-  the current one is probed. HBM traffic = n_runs × block_bytes — the
-  quantity IDL minimizes.
-* Within a resident tile the probe gather is done MXU-natively with two
-  one-hot matmuls (word-row pick, then bit-column pick) — no scalar loads,
-  no unsupported vector gathers.
+  id; the BlockSpec index_map consumes it, so Pallas DMAs exactly ONE tile
+  from HBM per run and double-buffers the next tile while the current one
+  is probed. HBM traffic = n_runs × block_bytes — the quantity IDL
+  minimizes.
+* Within a resident tile the gather is done MXU-natively with one-hot
+  matmuls — no scalar loads, no unsupported vector gathers.
 
-All lanes are uint32/int32/float32 (TPU has no 64-bit integer lanes).
+:func:`probe_rows` is the served kernel (every engine's query through
+``repro.index.query``); it compiles for the chip (see
+``repro.kernels.lanes`` and tests/test_tpu_compile.py). :func:`probe_runs`
+is the original flat-BF kernel, run only interpreted.
 """
 
 from __future__ import annotations
@@ -26,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import lanes
 
 
 def _probe_kernel(
@@ -64,83 +68,101 @@ def _probe_kernel(
 
 def _probe_rows_kernel(
     block_ids_ref,   # scalar-prefetch: (R,) int32 — matrix row-block per run
-    offsets_ref,     # (1, C) int32 — row offsets within the block (-1 = pad)
-    mat_ref,         # (rows_per_block, W) uint32 — the resident tile (VMEM)
-    out_ref,         # (1, C, W) uint32 — gathered row per lane
+    group_ids_ref,   # scalar-prefetch: (R,) int32 — output group per run
+    offsets_ref,     # (8, C) int32 — row offsets of 8 runs (-1 = other run)
+    mat_ref,         # (W, rows_per_block) uint32 — the resident tile (VMEM)
+    acc_ref,         # (W, C) uint32 — the group as earlier calls left it
+    out_ref,         # (W, C) uint32 — the run's output group, probe order
 ):
     del block_ids_ref  # consumed by the index_map only
-    offsets = offsets_ref[0, :]                      # (C,)
-    off = jnp.where(offsets >= 0, offsets, 0)        # pad lanes read row 0
+    i = pl.program_id(0)
+    off = lanes.run_lanes(offsets_ref, i)                       # (1, C)
+    rpb = mat_ref.shape[1]
+    # row gather as one one-hot matmul per byte plane (lanes of other runs
+    # select nothing and keep the group's earlier value)
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, (rpb, off.shape[1]), 0)
+              == off).astype(jnp.bfloat16)                      # (RPB, C)
+    tile = jax.lax.bitcast_convert_type(mat_ref[...], jnp.int32)
+    picked = lanes.planes_dot(tile, onehot, rhs_contract=0)     # (W, C)
+    mine = off >= 0
 
-    tile = mat_ref[...]                              # (RPB, W) uint32
-    rpb, w = tile.shape
-    # unpack the tile -> (RPB, W*32) bit image {0,1} (vector shifts only)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (rpb, w, 32), 2)
-    bits = ((tile[:, :, None] >> shifts) & jnp.uint32(1)).astype(jnp.float32)
-    bits2d = bits.reshape(rpb, w * 32)
+    # Runs are in probe order, so the runs of a group are consecutive: the
+    # first one starts the output block from the group's earlier value (a
+    # group may straddle two calls), the others fill in their lanes while
+    # it stays resident.
+    prev_run = jnp.maximum(i - 1, 0)
+    first = (i == 0) | (group_ids_ref[i] != group_ids_ref[prev_run])
 
-    c = offsets.shape[0]
-    # row gather via one one-hot matmul (MXU-native; {0,1} values are exact
-    # in f32), then an integer repack — f32 cannot hold full uint32 words
-    row_onehot = (
-        off[:, None] == jax.lax.broadcasted_iota(jnp.int32, (c, rpb), 1)
-    ).astype(jnp.float32)                            # (C, RPB)
-    picked = jnp.dot(
-        row_onehot, bits2d, preferred_element_type=jnp.float32
-    )                                                # (C, W*32) {0,1}
-    picked = picked.reshape(c, w, 32).astype(jnp.uint32)
-    sh = jax.lax.broadcasted_iota(jnp.uint32, (c, w, 32), 2)
-    out_ref[0, :, :] = jnp.sum(picked << sh, axis=2, dtype=jnp.uint32)
+    def fill_from(prev_ref):
+        prev = jax.lax.bitcast_convert_type(prev_ref[...], jnp.int32)
+        out_ref[...] = jax.lax.bitcast_convert_type(
+            jnp.where(mine, picked, prev), jnp.uint32)
+
+    pl.when(first)(lambda: fill_from(acc_ref))
+    pl.when(jnp.logical_not(first))(lambda: fill_from(out_ref))
 
 
 @functools.partial(
-    jax.jit, static_argnames=("rows_per_block", "probes_per_run", "interpret")
+    jax.jit,
+    static_argnames=("rows_per_block", "probes_per_run", "n_groups",
+                     "interpret"),
 )
 def probe_rows(
-    matrix: jax.Array,       # (n_rows, W) uint32 packed bit-matrix
+    matrix_t: jax.Array,     # (W, n_rows) uint32 packed bit-matrix, transposed
     block_ids: jax.Array,    # (R,) int32 row-block id per run
-    offsets: jax.Array,      # (R, C) int32 row offset in block, -1 padded
+    group_ids: jax.Array,    # (R,) int32 output group per run (nondecreasing)
+    offsets: jax.Array,      # (R, C) int32 row offset in block, -1 elsewhere
     *,
     rows_per_block: int,
     probes_per_run: int,
-    interpret: bool = True,
+    n_groups: int,
+    interpret: bool,
 ) -> jax.Array:
     """Run-coalesced row gather over an arbitrary packed bit-matrix.
 
-    The generalization of :func:`probe_runs` that serves every engine: one
-    grid step per run, one ``(rows_per_block, W)`` tile DMA'd per step (the
-    next tile double-buffers while the current one is probed), C row
-    gathers resolved MXU-natively inside the resident tile. Returns
-    ``(R, C, W)`` uint32 — the W-word row each probe hit (pad lanes
-    replicate row 0 of their block and must be masked by the caller's
-    ``probe_index`` scatter).
+    One grid step per run; one ``(W, rows_per_block)`` tile DMA'd per step
+    (the next tile double-buffers while the current one is probed). Probe
+    ``p`` of the stream is lane ``p % C`` of group ``p // C``, and a run
+    holds the probes of one block within one group. Returns
+    ``(W, n_groups * C)`` uint32: the W-word row of every probe, in probe
+    order, transposed like the matrix. ``R`` must be a multiple of 8.
     """
     r = block_ids.shape[0]
     c = probes_per_run
     if offsets.shape != (r, c):
         raise ValueError(f"offsets shape {offsets.shape} != {(r, c)}")
-    n_rows, w = matrix.shape
+    if r % lanes.RUNS_PER_BLOCK:
+        raise ValueError(f"run count {r} must be a multiple of "
+                         f"{lanes.RUNS_PER_BLOCK}")
+    w, n_rows = matrix_t.shape
     if n_rows % rows_per_block:
         raise ValueError(
             f"n_rows={n_rows} must be a multiple of rows_per_block="
             f"{rows_per_block}"
         )
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(r,),
-        in_specs=[
-            pl.BlockSpec((1, c), lambda i, bid: (i, 0)),
-            pl.BlockSpec((rows_per_block, w), lambda i, bid: (bid[i], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, c, w), lambda i, bid: (i, 0, 0)),
-    )
-    return pl.pallas_call(
-        _probe_rows_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r, c, w), jnp.uint32),
-        interpret=interpret,
-    )(block_ids, offsets, matrix)
+    lane_spec = pl.BlockSpec((lanes.RUNS_PER_BLOCK, c),
+                             lambda i, bid, gid: (i // lanes.RUNS_PER_BLOCK, 0))
+    tile_spec = pl.BlockSpec((w, rows_per_block),
+                             lambda i, bid, gid: (0, bid[i]))
+    group_spec = pl.BlockSpec((w, c), lambda i, bid, gid: (0, gid[i]))
+    out = jnp.zeros((w, n_groups * c), jnp.uint32)
+    for part in lanes.chunks(r):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(part.stop - part.start,),
+            in_specs=[lane_spec, tile_spec, group_spec],
+            out_specs=group_spec,
+        )
+        with lanes.x32():
+            out = pl.pallas_call(
+                _probe_rows_kernel,
+                grid_spec=grid_spec,
+                out_shape=jax.ShapeDtypeStruct(out.shape, jnp.uint32),
+                input_output_aliases={4: 0},
+                interpret=interpret,
+            )(block_ids[part], group_ids[part], offsets[part], matrix_t, out)
+    return out
 
 
 @functools.partial(
@@ -153,7 +175,7 @@ def probe_runs(
     *,
     block_words: int,
     probes_per_run: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Returns (R, C) int32 probed bits (pad lanes read as 1)."""
     r = block_ids.shape[0]

@@ -16,31 +16,28 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import lanes
 from repro.kernels.idl_probe import kernel, ref
 
 
 @dataclasses.dataclass
 class ProbePlan:
-    block_ids: np.ndarray    # (R,) int32
-    offsets: np.ndarray      # (R, C) int32, -1 padded
-    run_lengths: np.ndarray  # (R,) int32 probes per run (== row-wise count
-                             # of valid offsets, precomputed at plan time
-                             # so telemetry never re-reduces the (R, C)
-                             # offset matrix)
-    probe_index: np.ndarray  # (R, C) int32 position in flattened (η·n) stream
-    gather_index: np.ndarray # (n_probes,) int32 flat (run, lane) per probe —
-                             # the inverse of probe_index, so executors can
-                             # realign with a cheap gather instead of a
-                             # scatter over padded lanes
+    block_ids: np.ndarray    # (R_pad,) int32 block per run
+    group_ids: np.ndarray    # (R_pad,) int32 output group per run
+    offsets: np.ndarray      # (R_pad, C) int32 offset in block of the
+                             # probe at lane p % C of the run's group, -1
+                             # on lanes that belong to other runs
+    run_lengths: np.ndarray  # (n_runs,) int32 probes per run (precomputed
+                             # at plan time so telemetry never re-reduces
+                             # the (R, C) offset matrix)
+    probe_index: np.ndarray  # (R_pad, C) int32 position in flattened (η·n)
+                             # stream, -1 where offsets is
+    n_runs: int              # true run count (before padding)
     n_probes: int
     eta: int
     n_keys: int
     block_bits: int
     probes_per_run: int
-
-    @property
-    def n_runs(self) -> int:
-        return int(self.block_ids.shape[0])
     # NOTE: per-run DMA bytes depend on the probed matrix's row width,
     # which the plan does not know — see QueryPlan.run_dma_bytes.
 
@@ -53,9 +50,15 @@ def plan_probe_runs(
     ``locs`` may be bit locations (``block_bits`` = bits per block, the
     original flat-BF use) or matrix row indices (``block_bits`` = rows per
     block — the generalized ``probe_rows`` path); the arithmetic is
-    identical. Leading rows (hash repetitions, or batch × η streams) are
-    planned independently and concatenated, so a run never crosses streams.
-    Runs longer than C are split.
+    identical. The flattened stream is cut into groups of C consecutive
+    probes (probe ``p`` is lane ``p % C`` of group ``p // C``), and a run
+    is a stretch of probes of one block within one group, so the kernel
+    writes each group once, in probe order.
+
+    The run count is padded to a power of two, and at least 8 (pad runs
+    repeat the last run's block and group and probe nothing), so the
+    executor's compile cache stays small although the true run count is
+    data-dependent.
     """
     locs = np.asarray(locs, dtype=np.int64)
     if locs.ndim == 1:
@@ -72,28 +75,29 @@ def plan_probe_runs(
     start = np.empty(p * n, dtype=bool)
     start[0] = True
     np.not_equal(blocks[1:], blocks[:-1], out=start[1:])
-    start[:: n] = True                       # a run never crosses streams
-    pos_in_run = idx - np.maximum.accumulate(np.where(start, idx, 0))
-    # new segment at a run start or every C probes (split long runs); run
-    # keys are nondecreasing along the stream so a cumsum IS the inverse
-    # np.unique used to compute
-    seg = np.cumsum(start | (pos_in_run % c == 0)) - 1
-    n_runs = int(seg[-1]) + 1
-    pos = pos_in_run % c
+    start[:: c] = True                       # a run never crosses a group
+    # run keys are nondecreasing along the stream, so a cumsum numbers them
+    run = np.cumsum(start) - 1
+    n_runs = int(run[-1]) + 1
+    r_pad = max(1 << (n_runs - 1).bit_length(), lanes.RUNS_PER_BLOCK)
+    lane = idx % c
 
-    offs = np.full((n_runs, c), -1, dtype=np.int32)
-    pidx = np.full((n_runs, c), -1, dtype=np.int32)
-    offs[seg, pos] = (flat % block_bits).astype(np.int32)
-    pidx[seg, pos] = idx.astype(np.int32)
-    bids = np.zeros(n_runs, dtype=np.int32)
-    bids[seg] = blocks.astype(np.int32)
+    offs = np.full((r_pad, c), -1, dtype=np.int32)
+    pidx = np.full((r_pad, c), -1, dtype=np.int32)
+    offs[run, lane] = (flat % block_bits).astype(np.int32)
+    pidx[run, lane] = idx.astype(np.int32)
+    bids = np.full(r_pad, blocks[-1], dtype=np.int32)
+    bids[run] = blocks.astype(np.int32)
+    gids = np.full(r_pad, (p * n - 1) // c, dtype=np.int32)
+    gids[run] = (idx // c).astype(np.int32)
 
     return ProbePlan(
         block_ids=bids,
+        group_ids=gids,
         offsets=offs,
-        run_lengths=np.bincount(seg, minlength=n_runs).astype(np.int32),
+        run_lengths=np.bincount(run, minlength=n_runs).astype(np.int32),
         probe_index=pidx,
-        gather_index=(seg * c + pos).astype(np.int32),
+        n_runs=n_runs,
         n_probes=p * n,
         eta=p,
         n_keys=n,
@@ -103,7 +107,7 @@ def plan_probe_runs(
 
 
 def probe_membership(
-    bf_words: jax.Array, plan: ProbePlan, *, interpret: bool = True,
+    bf_words: jax.Array, plan: ProbePlan, *, interpret: bool,
     use_ref: bool = False,
 ) -> jax.Array:
     """Execute the plan; return (n_keys,) bool membership (AND over η)."""
@@ -128,30 +132,21 @@ def probe_membership(
 
 
 def gather_planned_rows(
-    matrix: jax.Array, plan: ProbePlan, *, interpret: bool = True,
+    matrix: jax.Array, plan: ProbePlan, *, interpret: bool,
     use_ref: bool = False,
 ) -> jax.Array:
     """Execute a row plan; return (n_probes, W) uint32 rows in probe order.
 
     ``plan.block_bits`` is interpreted as rows-per-block. ``use_ref`` swaps
-    the Pallas kernel for the fused pure-jnp oracle — same plan, same
-    result; the default executor on hosts without a Mosaic target.
-
-    The run count is padded to a power of two (pad runs are all-pad lanes
-    of block 0) so the executor's compile cache stays small even though
-    the true run count is data-dependent.
+    the Pallas kernel for the pure-jnp oracle — same plan, same result;
+    the executor on hosts without a Mosaic target.
     """
-    r = plan.n_runs
-    r_pad = 1 << max(r - 1, 1).bit_length()
-    bids = np.zeros((r_pad,), dtype=np.int32)
-    bids[:r] = plan.block_ids
-    offs = np.full((r_pad, plan.probes_per_run), -1, dtype=np.int32)
-    offs[:r] = plan.offsets
     return _planned_gather(
-        matrix, jnp.asarray(bids), jnp.asarray(offs),
-        jnp.asarray(plan.gather_index),
+        matrix, jnp.asarray(plan.block_ids), jnp.asarray(plan.group_ids),
+        jnp.asarray(plan.offsets),
         rows_per_block=plan.block_bits,
         probes_per_run=plan.probes_per_run,
+        n_probes=plan.n_probes,
         row_words=int(matrix.shape[-1]) if matrix.ndim > 1 else 1,
         interpret=interpret,
         use_ref=use_ref,
@@ -159,23 +154,27 @@ def gather_planned_rows(
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "rows_per_block", "probes_per_run", "row_words", "interpret", "use_ref",
+    "rows_per_block", "probes_per_run", "n_probes", "row_words",
+    "interpret", "use_ref",
 ))
-def _planned_gather(matrix, bids, offs, gidx, *, rows_per_block,
-                    probes_per_run, row_words, interpret, use_ref):
-    """One fused call: run the kernel (or ref), then realign to probe order
-    with the plan's precomputed inverse permutation (a cheap gather — pad
-    lanes are never referenced by ``gather_index``)."""
+def _planned_gather(matrix, bids, gids, offs, *, rows_per_block,
+                    probes_per_run, n_probes, row_words, interpret, use_ref):
+    """One fused call: run the kernel (or ref) and cut the groups' padding.
+    The kernel takes and returns the transposed view (see
+    ``repro.kernels.lanes``)."""
     matrix = jnp.reshape(matrix, (-1, row_words))
+    n_groups = -(-n_probes // probes_per_run)
     if use_ref:
-        runs = ref.probe_rows_ref(
-            matrix, bids, offs, rows_per_block=rows_per_block)
+        rows = ref.probe_rows_ref(
+            matrix, bids, gids, offs, rows_per_block=rows_per_block,
+            n_groups=n_groups)
     else:
-        runs = kernel.probe_rows(
-            matrix, bids, offs, rows_per_block=rows_per_block,
-            probes_per_run=probes_per_run, interpret=interpret,
-        )
-    return runs.reshape(-1, row_words)[gidx]
+        rows = kernel.probe_rows(
+            matrix.T, bids, gids, offs, rows_per_block=rows_per_block,
+            probes_per_run=probes_per_run, n_groups=n_groups,
+            interpret=interpret,
+        ).T
+    return rows[:n_probes]
 
 
 def scatter_and_reduce(bits: jax.Array, plan: ProbePlan) -> jax.Array:

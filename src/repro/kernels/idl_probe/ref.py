@@ -29,14 +29,21 @@ def probe_runs_ref(
 def probe_rows_ref(
     matrix: jax.Array,
     block_ids: jax.Array,
+    group_ids: jax.Array,
     offsets: jax.Array,
     *,
     rows_per_block: int,
+    n_groups: int,
 ) -> jax.Array:
-    """(R, C, W) uint32 gathered rows; pad lanes (offset < 0) read row 0."""
-    off = jnp.where(offsets >= 0, offsets, 0)
-    rows = block_ids[:, None] * rows_per_block + off
-    return matrix[rows]
+    """(n_groups·C, W) uint32 rows in probe order (untransposed, unlike
+    the kernel); the padding lanes of the last group read row 0."""
+    c = offsets.shape[1]
+    n_out = n_groups * c
+    lane = group_ids[:, None] * c + jnp.arange(c, dtype=jnp.int32)
+    rows = block_ids[:, None] * rows_per_block + offsets
+    at = jnp.zeros((n_out,), dtype=jnp.int32).at[
+        jnp.where(offsets >= 0, lane, n_out)].set(rows, mode="drop")
+    return matrix[at]
 
 
 def query_membership_ref(bf_words: jax.Array, locs: jax.Array) -> jax.Array:

@@ -34,7 +34,7 @@ def _window_min_kernel(a_ref, nxt_ref, out_ref, *, w: int):
 
 @functools.partial(jax.jit, static_argnames=("w", "tile", "interpret"))
 def window_min(
-    a: jax.Array, *, w: int, tile: int = 1024, interpret: bool = True
+    a: jax.Array, *, w: int, tile: int = 1024, interpret: bool
 ) -> jax.Array:
     """out[i] = min(a[i : i + w]) for all n − w + 1 windows.
 

@@ -2,33 +2,58 @@
 
 Builds a gene-search index over a synthetic archive and serves batched MSMT
 queries through the v2 engine + service path — the runnable counterpart of
-the serve cell the dry-run lowers. ``--procs N`` serves the same traffic
-through a :class:`ProcessFabric` instead: the index is snapshotted once
-and N worker processes mmap it behind one gateway. ``--shards N``
-partitions the index into N shard states, saves the shard-set snapshot,
-and serves through a :class:`ScatterGatherRouter` — each shard a worker
-process when ``--procs`` is also set — then runs the same recall check
-against the merged answers.
+the serve cell the dry-run lowers. By default the arch's full config (for
+``idl-genesearch`` the paper's 1024-file, m = 2^26 bit-sliced index, an
+8 GiB state on one chip); ``--smoke`` takes its small config. Only the
+genome length per file is cut, and it is printed as a reduction. The
+index is built through the ``idl_insert`` ingest backend and served
+through the ``idl_probe`` query backend. ``--procs N`` serves
+the same traffic through a :class:`ProcessFabric` instead: the index is
+snapshotted once and N worker processes mmap it behind one gateway.
+``--shards N`` partitions the index into N shard states, saves the
+shard-set snapshot, and serves through a :class:`ScatterGatherRouter` —
+each shard a worker process when ``--procs`` is also set — then runs the
+same recall check against the merged answers. The process tiers run on a
+CPU host only (a chip belongs to one process).
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import tempfile
 import time
 
+import jax
 import numpy as np
 
 import repro.configs as configs
 from repro.data import genome
-from repro.index import BitSlicedIndex
+from repro.index import BitSlicedIndex, ingest
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving import GeneSearchService, ServiceConfig
+
+
+def build_index(cfg, archive, *, chunk_reads: int = 64) -> BitSlicedIndex:
+    """The bit-sliced index over ``archive``, built through the
+    ``idl_insert`` ingest backend in ``chunk_reads``-read batches of
+    ``cfg.read_len``-bp windows. On a CPU host the plans execute with the
+    kernel's jnp oracle (no Mosaic target there), as the service does for
+    queries."""
+    eng = BitSlicedIndex.build(cfg.idl_config(), cfg.scheme, cfg.n_files)
+    return ingest.build_archive(eng, archive, read_len=cfg.read_len,
+                                chunk_reads=chunk_reads, backend="idl_insert",
+                                use_ref=jax.default_backend() == "cpu")
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="idl-genesearch")
-    ap.add_argument("--files", type=int, default=32)
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the arch's small smoke config instead of "
+                         "its full config")
+    ap.add_argument("--files", type=int, default=None,
+                    help="files in the archive (default: the config's)")
     ap.add_argument("--batch", type=int, default=64)
     ap.add_argument("--requests", type=int, default=5)
     ap.add_argument("--procs", type=int, default=0, metavar="N",
@@ -48,25 +73,31 @@ def main() -> None:
     if spec.family != "genesearch":
         raise SystemExit("serve launcher currently drives idl-genesearch; "
                          "LM decode is exercised via the dry-run cells")
-    cfg = spec.make_smoke_config()
-    import dataclasses
-    args.files = max(32, -(-args.files // 32) * 32)  # bit-sliced: 32/word
+    print(f"compile cache: {use_compile_cache()}")
+    cfg = spec.make_smoke_config() if args.smoke else spec.make_config()
+    n_files = cfg.n_files if args.files is None else args.files
+    n_files = max(32, -(-n_files // 32) * 32)  # bit-sliced: 32/word
     if args.shards:
         # file shards split on 32-file word columns: one column per shard
         # is the floor
-        args.files = max(args.files, 32 * args.shards)
-    cfg = dataclasses.replace(cfg, n_files=args.files)
+        n_files = max(n_files, 32 * args.shards)
+    cfg = dataclasses.replace(cfg, n_files=n_files)
+    genome_len = 2_000 if args.smoke else 20_000
+    print(f"config {cfg.name}: {n_files} files, m={cfg.m}, k={cfg.k}, "
+          f"eta={cfg.eta}, L={cfg.L}, read_len={cfg.read_len}; reduced: "
+          f"genome length {genome_len} bp per file (seeded synthetic)")
 
-    archive = genome.synth_archive(n_files=args.files, genome_len=2_000,
+    archive = genome.synth_archive(n_files=n_files, genome_len=genome_len,
                                    seed=11)
-    eng = BitSlicedIndex.build(cfg.idl_config(), cfg.scheme, cfg.n_files)
-    for f in archive:
-        eng = eng.insert_batch(np.asarray(f.genome)[None],
-                               np.asarray([f.file_id], dtype=np.int32))
-    print(f"index: {args.files} files, "
-          f"{eng.state.nbytes / 1e6:.1f} MB bit-sliced IndexState")
+    t0 = time.perf_counter()
+    eng = build_index(cfg, archive)
+    jax.block_until_ready(eng.words)
+    print(f"index: {n_files} files, "
+          f"{eng.state.nbytes / 1e6:.1f} MB bit-sliced IndexState, built "
+          f"with idl_insert in {time.perf_counter() - t0:.1f} s")
 
-    svc_cfg = ServiceConfig(theta=cfg.theta, max_batch=args.batch)
+    svc_cfg = ServiceConfig(theta=cfg.theta, backend="idl_probe",
+                            max_batch=args.batch)
     if args.shards:
         from repro.index import shards as shards_mod
         from repro.serving import ScatterConfig, ScatterGatherRouter
@@ -98,7 +129,7 @@ def main() -> None:
     lat = []
     correct = total = 0
     for r in range(args.requests):
-        fids = rng.integers(0, args.files, size=args.batch)
+        fids = rng.integers(0, n_files, size=args.batch)
         reads = [np.asarray(archive[int(f)].reads(cfg.read_len, 1)[0])
                  for f in fids]
         t0 = time.perf_counter()

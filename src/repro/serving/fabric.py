@@ -312,6 +312,7 @@ class ProcessFabric:
                  config: Optional[FabricConfig] = None, *,
                  journal_path: Optional[str] = None,
                  base_version: int = 0):
+        ipc.require_cpu_backend("ProcessFabric", FabricError)
         self.config = config or FabricConfig()
         # O(manifest): the gateway learns kmer size + bucket geometry
         # without ever paging the index in

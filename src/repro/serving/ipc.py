@@ -36,6 +36,8 @@ import struct
 import threading
 from typing import Optional
 
+import jax
+
 __all__ = [
     "Hello",
     "Request",
@@ -184,3 +186,20 @@ def connect(path: str, timeout_s: float = 30.0) -> Wire:
     sock.connect(path)
     sock.settimeout(None)
     return Wire(sock)
+
+
+def require_cpu_backend(tier: str, error: type) -> None:
+    """Raise ``error`` unless JAX's backend is the CPU.
+
+    A process tier spawns workers that each import JAX and take the
+    device. A TPU chip belongs to one process at a time, so on a chip host
+    those workers would wait for it until their boot timeout: refuse
+    before spawning any of them.
+    """
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise error(
+            f"{tier} runs its workers in separate processes, but a "
+            f"{backend} chip belongs to one process; serve from this "
+            f"process instead (GeneSearchService or ReplicaRouter, one "
+            f"replica per device)")

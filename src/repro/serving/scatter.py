@@ -328,6 +328,9 @@ class ScatterGatherRouter:
     def __init__(self, shard_set_dir: str,
                  config: Optional[ScatterConfig] = None):
         self.config = config or ScatterConfig()
+        if self.config.procs:
+            ipc.require_cpu_backend("ScatterGatherRouter(procs=True)",
+                                    ScatterError)
         self._dir = shard_set_dir
         # O(manifest): geometry + version from the CRC-checked set
         # manifest; the gateway itself never pages shard bytes in

@@ -9,6 +9,7 @@ suite keeps fleets small (2 workers) and shares one serving fleet across
 the non-destructive tests.
 """
 
+import multiprocessing
 import os
 import signal
 import time
@@ -27,6 +28,9 @@ from repro.serving import (
     FabricError,
     KmerCacheConfig,
     ProcessFabric,
+    ScatterConfig,
+    ScatterError,
+    ScatterGatherRouter,
     ServiceConfig,
 )
 
@@ -357,3 +361,20 @@ class TestObservabilityAcrossTheFleet:
             assert any(r["trace"] in ok_hops for r in orphans)
         finally:
             fab.close()
+
+
+@pytest.mark.parametrize("tier", ["fabric", "scatter_procs"])
+def test_process_tiers_refuse_a_chip_backend(tier, tmp_path, monkeypatch):
+    """On a TPU host the tier's workers could never take the chip (it
+    belongs to one process): construction raises before any spawn,
+    instead of waiting out the boot timeout."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    before = set(multiprocessing.active_children())
+    if tier == "fabric":
+        with pytest.raises(FabricError, match="belongs to one process"):
+            ProcessFabric(str(tmp_path), _fab_cfg())
+    else:
+        with pytest.raises(ScatterError, match="belongs to one process"):
+            ScatterGatherRouter(str(tmp_path), ScatterConfig(procs=True))
+    assert set(multiprocessing.active_children()) == before

@@ -1,0 +1,115 @@
+"""The main path's Pallas kernels compile for a TPU v5e at full size.
+
+No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
+topology. The shapes are the ``idl-genesearch`` config's: the
+``(2^26, 32)`` uint32 bit-sliced matrix, 512 rows per block (what
+``plan_query`` derives for it) and 128 probes per run (its TPU default),
+with ``repro`` imported, so ``jax_enable_x64`` is on as in the server.
+The topology is described inside a fixture: only the process that runs
+these tests loads the TPU compiler.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import repro  # noqa: F401 - x64 on, as every process that serves
+import repro.configs as configs
+from repro.index import query
+from repro.kernels import lanes
+from repro.kernels.idl_insert import kernel as insert_kernel
+from repro.kernels.idl_probe import kernel as probe_kernel
+
+CFG = configs.get("idl-genesearch").make_config()
+C = 128                                   # probes / inserts per run
+RUNS = 4 * lanes.MAX_RUNS_PER_CALL        # a 256-read batch, several calls
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def shapes(one_chip):
+    """Kernel operands at full size, on the described chip."""
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return {
+        "matrix_t": spec((CFG.file_words, CFG.m), jnp.uint32),
+        "ids": spec((RUNS,), jnp.int32),
+        "lanes": spec((RUNS, C), jnp.int32),
+    }
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one: keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _rows_per_block() -> int:
+    plan = query.plan_query(
+        CFG.idl_config(), CFG.scheme, (256, CFG.read_len),
+        (CFG.m, CFG.file_words), bit_probe=False, lane32=True,
+        probes_per_run=C)
+    return plan.rows_per_block
+
+
+def test_full_config_block_geometry():
+    assert _rows_per_block() == 512
+    assert CFG.m * CFG.file_words * 4 == 8 * 2**30
+
+
+def test_probe_rows_compiles_for_v5e(shapes, no_compile_cache):
+    rpb = _rows_per_block()
+    n_groups = RUNS // 2
+    compiled = jax.jit(
+        lambda m, b, g, o: probe_kernel.probe_rows(
+            m, b, g, o, rows_per_block=rpb, probes_per_run=C,
+            n_groups=n_groups, interpret=False)
+    ).lower(shapes["matrix_t"], shapes["ids"], shapes["ids"],
+            shapes["lanes"]).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # the (W, n_rows) view is lane-dense: the matrix goes in as 8 GiB,
+    # with no relayout copy of it next to the output
+    assert mem.argument_size_in_bytes < 8 * 2**30 + 2**28
+    assert mem.temp_size_in_bytes < 2**30
+    assert mem.output_size_in_bytes == CFG.file_words * n_groups * C * 4
+
+
+def test_insert_runs_compiles_for_v5e(shapes, no_compile_cache):
+    compiled = jax.jit(
+        lambda m, b, r, c: insert_kernel.insert_runs(
+            m, b, r, c, rows_per_block=_rows_per_block(),
+            inserts_per_run=C, interpret=False),
+        donate_argnums=0,
+    ).lower(shapes["matrix_t"], shapes["ids"], shapes["lanes"],
+            shapes["lanes"]).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # updated in place: the output is the donated matrix itself
+    assert mem.alias_size_in_bytes == 8 * 2**30
+    assert mem.temp_size_in_bytes < 2**30
