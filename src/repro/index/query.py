@@ -69,6 +69,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import idl as idl_mod
 from repro.index import packed
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 
 BACKENDS = ("jnp", "idl_probe", "sharded")
 MESH_AXIS = "shards"
@@ -243,14 +244,16 @@ class QueryPlan:
         """
         from repro.kernels.idl_probe import ops as probe_ops
 
-        locs = np.asarray(self.locations(reads))
+        with obs_trace.stage("plan.locations"):
+            locs = np.asarray(self.locations(reads))
         rows = (locs >> 5) if self.bit_probe else locs
         b, eta, n_k = locs.shape
-        rplan = probe_ops.plan_probe_runs(
-            rows.reshape(b * eta, n_k),
-            block_bits=self.rows_per_block,
-            probes_per_run=self.probes_per_run,
-        )
+        with obs_trace.stage("plan.runs"):
+            rplan = probe_ops.plan_probe_runs(
+                rows.reshape(b * eta, n_k),
+                block_bits=self.rows_per_block,
+                probes_per_run=self.probes_per_run,
+            )
         return rplan, locs
 
     def run_dma_bytes(self, rplan) -> int:
@@ -303,14 +306,17 @@ class QueryPlan:
 
         if interpret is None:
             interpret = jax.default_backend() == "cpu"
-        rplan, locs = self.plan_runs(reads)
-        record_locality(
-            scheme=self.scheme, op="query",
-            tile_bytes=self.run_dma_bytes(rplan), n_runs=rplan.n_runs,
-            n_probes=int(rplan.n_probes), run_lengths=rplan.run_lengths)
-        gathered = probe_ops.gather_planned_rows(
-            matrix, rplan, interpret=interpret, use_ref=use_ref,
-        )                                           # (n_probes, W)
+        # the host run planner, from the locations through the kernel's
+        # (asynchronous) launch
+        with obs_trace.stage("plan"):
+            rplan, locs = self.plan_runs(reads)
+            record_locality(
+                scheme=self.scheme, op="query",
+                tile_bytes=self.run_dma_bytes(rplan), n_runs=rplan.n_runs,
+                n_probes=int(rplan.n_probes), run_lengths=rplan.run_lengths)
+            gathered = probe_ops.gather_planned_rows(
+                matrix, rplan, interpret=interpret, use_ref=use_ref,
+            )                                       # (n_probes, W)
         b, eta, n_k = locs.shape
         gathered = gathered.reshape(b, eta, n_k, self.row_words)
         return _finish_probe(

@@ -161,6 +161,7 @@ def probe_rows(
                 out_shape=jax.ShapeDtypeStruct(out.shape, jnp.uint32),
                 input_output_aliases={4: 0},
                 interpret=interpret,
+                name="probe_rows",
             )(block_ids[part], group_ids[part], offsets[part], matrix_t, out)
     return out
 

@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.kernels import lanes
 from repro.kernels.idl_probe import kernel, ref
+from repro.obs import trace as obs_trace
 
 
 @dataclasses.dataclass
@@ -141,9 +142,11 @@ def gather_planned_rows(
     the Pallas kernel for the pure-jnp oracle — same plan, same result;
     the executor on hosts without a Mosaic target.
     """
+    with obs_trace.stage("plan.upload"):
+        runs = (jnp.asarray(plan.block_ids), jnp.asarray(plan.group_ids),
+                jnp.asarray(plan.offsets))
     return _planned_gather(
-        matrix, jnp.asarray(plan.block_ids), jnp.asarray(plan.group_ids),
-        jnp.asarray(plan.offsets),
+        matrix, *runs,
         rows_per_block=plan.block_bits,
         probes_per_run=plan.probes_per_run,
         n_probes=plan.n_probes,

@@ -15,9 +15,8 @@ re-based once at import) so spans from one host line up on a shared
 timeline in the Chrome viewer.
 
 Finished spans are plain dicts in a bounded ring (:class:`Tracer`), never
-an unbounded log. Two export shapes: ``export()`` groups records by trace
-id (JSON), ``export_chrome()`` emits ``trace_event`` "X" (complete)
-events loadable by chrome://tracing / Perfetto.
+an unbounded log; :mod:`repro.obs.export` renders them as JSON grouped by
+trace id or as Chrome ``trace_event`` events.
 
 Hot-path discipline: the batch pipeline does not build Span objects per
 request mid-flight — it stamps monotonic times it mostly already takes,
@@ -25,6 +24,14 @@ and emits finished records in one pass at finalize (:meth:`Tracer.emit`).
 An open :class:`Span` object is only held where someone must be able to
 *close it with an error later* (gateway-side dispatch spans, so a worker
 death closes them instead of leaking them).
+
+Stages (:class:`stage`) are the other half: batch-level intervals of the
+serving pipeline and the host run planner, timed where the work happens.
+Each one is a ``jax.profiler.TraceAnnotation``, so a profiler session
+records it on its host plane on the same clock as the device's ops, and
+its duration lands in the registry histogram ``serving.stage_ms``
+labelled ``stage=<name>``. No ring holds them: outside a profiler session
+only the histogram sees them.
 """
 
 from __future__ import annotations
@@ -35,6 +42,10 @@ import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
+
+from jax import profiler as jax_profiler
+
+from repro.obs import metrics as obs_metrics
 
 # Trace context as it rides an IPC frame: (trace_id, parent_span_id).
 TraceContext = Tuple[str, str]
@@ -202,35 +213,10 @@ class Tracer:
                            attrs or None))
         return span_id
 
-    def emit_chain(self, trace_id: str, parent: Optional[str],
-                   root_name: str, t_root0: float, t_root1: float,
-                   children, status: str = "ok",
-                   root_attrs: Optional[dict] = None) -> Optional[str]:
-        """Emit a root span plus already-timed children in ONE call — the
-        per-request chain the batch pipeline produces at finalize
-        (request + queue_wait/assemble/execute/finalize). ``children`` is
-        a sequence of ``(name, t0_mono, t1_mono)``. Everything is local
-        variables and tuple literals: per-request tracing costs a couple
-        of microseconds instead of five function-call round trips each
-        building a dict. Returns the root span id, or None when
-        disabled."""
-        if not self.enabled:
-            return None
-        seq, prefix, pid = self._seq, self._prefix, self._pid
-        append = self._ring.append
-        root = prefix + "%x" % next(seq)
-        append((trace_id, root, parent, root_name, pid,
-                _EPOCH0 + t_root0, t_root1 - t_root0, status,
-                root_attrs or None))
-        for name, ta, tb in children:
-            append((trace_id, prefix + "%x" % next(seq), root, name, pid,
-                    _EPOCH0 + ta, tb - ta, status, None))
-        return root
-
     def emit_request_chains(self, entries, q_end: float, stages,
                             t_done: float, status: str = "ok",
                             shared_attrs: Optional[dict] = None) -> None:
-        """Batched :meth:`emit_chain` for one finalized batch: every entry
+        """Emit the span chains of one finalized batch: every entry
         gets a root ``request`` span ending at ``t_done`` with a private
         ``queue_wait`` child (admission → ``q_end``) plus the batch-shared
         ``stages`` children (``(name, t0_mono, t1_mono)`` with identical
@@ -240,9 +226,7 @@ class Tracer:
         attrs template — is hoisted out of the per-request loop, and each
         request mints ONE sequence id: its children derive their span ids
         from the root (``<root>.q``, ``<root>.0``...), which is unique by
-        construction and skips five format/concat rounds per request.
-        This is why the batch pipeline calls this instead of per-request
-        :meth:`emit_chain`."""
+        construction and skips five format/concat rounds per request."""
         if not self.enabled:
             return
         seq, prefix, pid = self._seq, self._prefix, self._pid
@@ -276,32 +260,6 @@ class Tracer:
         """Finished records as public dicts, oldest first (a copy)."""
         return [_to_dict(rec) for rec in self._ring]
 
-    def export(self) -> dict:
-        """JSON shape: records grouped per trace id, each trace's spans
-        sorted by start time."""
-        traces: Dict[str, List[dict]] = {}
-        for rec in self.records():
-            traces.setdefault(rec["trace"], []).append(rec)
-        for spans in traces.values():
-            spans.sort(key=lambda r: r["t0"])
-        return {"pid": self._pid, "n_spans": sum(map(len, traces.values())),
-                "traces": traces}
-
-    def export_chrome(self) -> dict:
-        """Chrome ``trace_event`` JSON (load in chrome://tracing or
-        Perfetto): one "X" complete event per span, ts/dur in µs, pid =
-        origin process, tid = trace id (one row per request)."""
-        events = []
-        for rec in self.records():
-            ev = {"name": rec["name"], "ph": "X", "cat": rec["status"],
-                  "ts": rec["t0"] * 1e6, "dur": rec["dur"] * 1e6,
-                  "pid": rec["pid"], "tid": rec["trace"],
-                  "args": {"span": rec["span"],
-                           "parent": rec["parent"],
-                           **rec.get("attrs", {})}}
-            events.append(ev)
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
-
     def clear(self) -> None:
         self._ring.clear()
 
@@ -316,3 +274,47 @@ def tracer() -> Tracer:
 
 def set_enabled(enabled: bool) -> None:
     DEFAULT.enabled = bool(enabled)
+
+
+class stage:
+    """Time one stage of the serving pipeline or the host run planner::
+
+        with trace.stage("plan.runs"):
+            ...
+
+    On entry it opens ``jax.profiler.TraceAnnotation(name)``, so a profiler
+    session records the interval on its host plane, on the device trace's
+    clock; on exit it observes the duration in ms into the registry
+    histogram ``serving.stage_ms{stage=<name>}``. With the default tracer
+    disabled (``repro.obs.set_enabled(False)``) it does nothing. A fresh
+    object per use: stages of one name run on several threads at once."""
+
+    __slots__ = ("_hist", "_note", "_t0")
+
+    def __init__(self, name: str):
+        self._hist = _stage_hist(name) if DEFAULT.enabled else None
+        if self._hist is not None:
+            self._note = jax_profiler.TraceAnnotation(name)
+
+    def __enter__(self) -> "stage":
+        if self._hist is not None:
+            self._note.__enter__()
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._hist is not None:
+            self._hist.observe((time.perf_counter() - self._t0) * 1e3)
+            self._note.__exit__(exc_type, exc, tb)
+
+
+_STAGE_HISTS: Dict[str, obs_metrics.Histogram] = {}
+
+
+def _stage_hist(name: str) -> obs_metrics.Histogram:
+    """The histogram of one stage, bound once per name."""
+    h = _STAGE_HISTS.get(name)
+    if h is None:
+        h = _STAGE_HISTS[name] = obs_metrics.DEFAULT.histogram(
+            "serving.stage_ms", stage=name)
+    return h

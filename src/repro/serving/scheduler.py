@@ -199,7 +199,6 @@ class AsyncScheduler:
                                 **labels)
             for reason in (FLUSH_FULL, FLUSH_DEADLINE, FLUSH_DRAIN)}
         self._obs_queue_ms = reg.histogram("scheduler.queue_ms", **labels)
-        self._obs_wall_ms = reg.histogram("scheduler.wall_ms", **labels)
         self._obs_writes = reg.counter("scheduler.write_batches", **labels)
         self._obs_write_reads = reg.counter("scheduler.write_reads",
                                             **labels)
@@ -566,7 +565,6 @@ class AsyncScheduler:
             self.stats.append(stats)
             self._obs_flushes[reason].inc()
             self._obs_queue_ms.observe(stats.queue_ms)
-            self._obs_wall_ms.observe(wall_ms)
             self._svc._record_batch(service_mod.BatchStats(
                 bucket=bucket, n_requests=len(take), batch_rows=rows,
                 pad_rows=rows - len(take),

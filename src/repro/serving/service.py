@@ -532,44 +532,49 @@ class GeneSearchService:
     def _assemble(self, take, bucket: int):
         """Pad ``take`` = [(request, n_kmers), ...] into the bucket's fixed
         batch shape (host-side; no device work)."""
-        rows, read_len = self.config.max_batch, bucket + self._k - 1
-        batch = np.zeros((rows, read_len), dtype=np.uint8)
-        valid = np.zeros((rows, bucket), dtype=bool)
-        need = np.zeros((rows,), dtype=np.int32)
-        for i, (req, n_k) in enumerate(take):
-            batch[i, :req.read.shape[0]] = req.read
-            valid[i, :n_k] = True
-            need[i] = query.coverage_need(self.config.theta, n_k)
-        for i in range(len(take), rows):       # pad rows replay row 0;
-            batch[i], valid[i], need[i] = batch[0], valid[0], need[0]
-        return batch, valid, need
+        with obs_trace.stage("serve.assemble"):
+            rows, read_len = self.config.max_batch, bucket + self._k - 1
+            batch = np.zeros((rows, read_len), dtype=np.uint8)
+            valid = np.zeros((rows, bucket), dtype=bool)
+            need = np.zeros((rows,), dtype=np.int32)
+            for i, (req, n_k) in enumerate(take):
+                batch[i, :req.read.shape[0]] = req.read
+                valid[i, :n_k] = True
+                need[i] = query.coverage_need(self.config.theta, n_k)
+            for i in range(len(take), rows):   # pad rows replay row 0;
+                batch[i], valid[i], need[i] = batch[0], valid[0], need[0]
+            return batch, valid, need
 
     def _execute(self, bucket: int, batch, valid, need):
         """Dispatch the bucket's compiled step; returns the on-device out."""
-        step, _ = self._runner(bucket)         # pad results are discarded
-        if self.kmer_cache is not None:
-            # host arrays straight through: the cached step packs and
-            # looks up on the host anyway (a jnp round-trip of the batch
-            # would be copied right back), and jit converts valid/need
-            return step(self._state, batch, valid, need)
-        return step(self._state, jnp.asarray(batch), jnp.asarray(valid),
-                    jnp.asarray(need))
+        with obs_trace.stage("serve.execute"):
+            step, _ = self._runner(bucket)     # pad results are discarded
+            if self.kmer_cache is not None:
+                # host arrays straight through: the cached step packs and
+                # looks up on the host anyway (a jnp round-trip of the
+                # batch would be copied right back), and jit converts
+                # valid/need
+                return step(self._state, batch, valid, need)
+            return step(self._state, jnp.asarray(batch), jnp.asarray(valid),
+                        jnp.asarray(need))
 
     def _finalize(self, take, bucket: int, out) -> List[SearchResult]:
         """Materialize the device output and decode per-request verdicts."""
-        out = np.asarray(out)                  # blocks until device done
-        single_set = self._state.meta.engine == "bloom"
-        results = []
-        for i, (req, n_k) in enumerate(take):
-            row = out[i]
-            if single_set:
-                fids = (0,) if bool(row) else ()
-            else:
-                fids = tuple(int(f) for f in np.nonzero(row)[0])
-            results.append(SearchResult(
-                request_id=req.request_id, matches=row, file_ids=fids,
-                n_kmers=n_k, bucket=bucket, version=self._version))
-        return results
+        with obs_trace.stage("serve.finalize"):
+            with obs_trace.stage("serve.finalize.wait"):
+                out = np.asarray(out)          # blocks until device done
+            single_set = self._state.meta.engine == "bloom"
+            results = []
+            for i, (req, n_k) in enumerate(take):
+                row = out[i]
+                if single_set:
+                    fids = (0,) if bool(row) else ()
+                else:
+                    fids = tuple(int(f) for f in np.nonzero(row)[0])
+                results.append(SearchResult(
+                    request_id=req.request_id, matches=row, file_ids=fids,
+                    n_kmers=n_k, bucket=bucket, version=self._version))
+            return results
 
     def _flush_bucket(self, bucket: int) -> None:
         queue = self._pending.get(bucket, [])

@@ -24,9 +24,11 @@ from repro.obs import export as obs_export
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.serving import (
+    AsyncScheduler,
     GeneSearchService,
     LiveReplicaRouter,
     RouterConfig,
+    SchedulerConfig,
     ServiceConfig,
 )
 
@@ -331,11 +333,12 @@ class TestTracer:
         w.end()
         root.end()
         gateway.ingest(worker.records())          # stitch worker records
-        exp = gateway.export()
-        assert exp["n_spans"] == 2
-        (spans,) = exp["traces"].values()         # ONE trace id
+        snap = obs_export.snapshot(registry=obs_metrics.Registry(),
+                                   tracer=gateway)
+        assert len(snap["spans"]) == 2
+        (spans,) = obs_export.traces_of(snap).values()    # ONE trace id
         assert {s["span"] for s in spans} == {root.span_id, w.span_id}
-        chrome = gateway.export_chrome()
+        chrome = obs_export.chrome_events(snap)
         assert len(chrome["traceEvents"]) == 2
         ev = chrome["traceEvents"][0]
         assert ev["ph"] == "X" and ev["tid"] == root.trace_id
@@ -380,6 +383,99 @@ class TestExport:
         chrome = json.loads(out.with_suffix(".chrome.json").read_text())
         assert len(chrome["traceEvents"]) == 2
         assert chrome["displayTimeUnit"] == "ms"
+
+
+# ---------------------------------------------------------------------------
+# stages: the pipeline's and the planner's intervals
+# ---------------------------------------------------------------------------
+
+# every stage the idl_probe query path times, and the stage holding each
+STAGES = {"serve.assemble": None, "serve.execute": None,
+          "plan": "serve.execute", "plan.locations": "plan",
+          "plan.runs": "plan", "plan.upload": "plan",
+          "serve.finalize": None, "serve.finalize.wait": "serve.finalize"}
+
+
+def _stage_ms(name: str):
+    """The ``serving.stage_ms`` histogram of one stage, or None."""
+    snap = obs_metrics.DEFAULT.snapshot()
+    return snap["hists"].get("serving.stage_ms", {}).get(f"stage={name}")
+
+
+class TestStages:
+
+    @pytest.fixture(autouse=True)
+    def _clean_obs(self):
+        obs.reset()
+        yield
+        obs.set_enabled(True)
+        obs.reset()
+
+    def test_stage_observes_its_duration_in_ms(self):
+        with obs_trace.stage("unit.sleep"):
+            time.sleep(0.02)
+        with pytest.raises(ValueError):        # errors pass through, timed
+            with obs_trace.stage("unit.sleep"):
+                raise ValueError("boom")
+        h = _stage_ms("unit.sleep")
+        assert h["count"] == 2
+        assert h["max"] >= 20.0 and h["min"] < 20.0
+
+    def test_stage_is_a_noop_when_obs_is_disabled(self):
+        obs.set_enabled(False)
+        with obs_trace.stage("unit.off"):
+            pass
+        obs.set_enabled(True)
+        h = _stage_ms("unit.off")
+        assert h is None or h["count"] == 0
+
+    def test_sync_service_times_every_stage_once_a_batch(self, base_engine,
+                                                           queries):
+        svc = GeneSearchService(
+            base_engine, ServiceConfig(backend="idl_probe", max_batch=4))
+        svc.search(queries)
+        counts = {name: _stage_ms(name)["count"] for name in STAGES}
+        batches = obs_metrics.counter_total(
+            obs_metrics.DEFAULT.snapshot(), "serving.batches")
+        assert batches >= 2
+        assert set(counts.values()) == {batches}
+
+    def test_stages_land_nested_on_the_profiler_host_plane(
+            self, base_engine, reads, tmp_path):
+        import jax
+        from jax.profiler import ProfileData
+
+        svc = GeneSearchService(
+            base_engine, ServiceConfig(backend="idl_probe", max_batch=4))
+        svc.search(list(reads[:4]))            # compile outside the trace
+        sched = AsyncScheduler(svc, SchedulerConfig(max_delay_ms=1.0))
+        try:
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                futures = [sched.submit(r) for r in reads[:4]]
+                sched.drain()
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            sched.close()
+        assert all(f.result().file_ids is not None for f in futures)
+        (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+        events: dict = {}
+        for plane in ProfileData.from_file(str(path)).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in STAGES:
+                        events.setdefault(e.name, []).append(
+                            (e.start_ns, e.end_ns))
+        assert set(events) == set(STAGES)
+        for name, outer in STAGES.items():
+            if outer is None:
+                continue
+            for s, t in events[name]:
+                assert any(s0 <= s and t <= t0 for s0, t0 in events[outer]), \
+                    f"{name} at {s}..{t} lies in no {outer}"
 
 
 # ---------------------------------------------------------------------------

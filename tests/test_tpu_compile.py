@@ -10,6 +10,7 @@ these tests loads the TPU compiler.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -98,6 +99,26 @@ def test_probe_rows_compiles_for_v5e(shapes, no_compile_cache):
     assert mem.argument_size_in_bytes < 8 * 2**30 + 2**28
     assert mem.temp_size_in_bytes < 2**30
     assert mem.output_size_in_bytes == CFG.file_words * n_groups * C * 4
+
+
+def test_probe_rows_custom_call_is_named(one_chip, no_compile_cache):
+    """The kernel's op in a device trace carries the name the benchmark
+    matches (``bench/kernels/probe_rows.py``), whatever the Python
+    functions around it are called."""
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    runs = 2 * lanes.RUNS_PER_BLOCK
+    compiled = jax.jit(
+        lambda m, b, g, o: probe_kernel.probe_rows(
+            m, b, g, o, rows_per_block=512, probes_per_run=C,
+            n_groups=runs, interpret=False)
+    ).lower(spec((CFG.file_words, 1 << 16), jnp.uint32),
+            spec((runs,), jnp.int32), spec((runs,), jnp.int32),
+            spec((runs, C), jnp.int32)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert calls
+    assert all(re.search(r"%probe_rows(\.\d+)? = ", c) for c in calls)
 
 
 def test_insert_runs_compiles_for_v5e(shapes, no_compile_cache):
