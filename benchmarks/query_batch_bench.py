@@ -8,7 +8,7 @@ measured per backend of :mod:`repro.index.query`:
   the unified layer: one jit'd per-read packed gather per read (the path
   the CI smoke run guards against regressing to);
 * ``jnp``       — the batched pure-XLA reference gather (one jit call);
-* ``idl_probe`` — the planned backend: host run-length planner + the
+* ``idl_probe`` — the planned backend: device run-length planner + the
   generalized run-coalesced executor (the Pallas ``probe_rows`` kernel on
   accelerators; its fused jnp oracle on CPU, where Mosaic is unavailable —
   same plan, bit-identical results);

@@ -99,7 +99,6 @@ class CompileClock:
 def one_chip(args, jax, cfg, clock) -> None:
     from repro.data import genome
     from repro.index import query
-    from repro.kernels.idl_probe import ops as probe_ops
     from repro.launch.serve import build_index
     from repro.serving import GeneSearchService, ServiceConfig
 
@@ -150,31 +149,31 @@ def one_chip(args, jax, cfg, clock) -> None:
           f"({answers['jnp'].shape[0]} reads x {cfg.n_files} files)")
     check(same, "idl_probe and jnp answers differ")
 
-    # The served idl_probe step is the jitted planned gather. Re-plan the
-    # last batch as the service padded it (reads of one length fill the
-    # bucket's leading columns, zeros after): if that call compiles
-    # nothing new, the service ran exactly this executable, with
-    # interpret and the oracle off, and its text names the Mosaic kernel.
+    # The served idl_probe step is one jitted program: hash, run planner
+    # and kernel. Run the last batch as the service padded it (reads of
+    # one length fill the bucket's leading columns, zeros after): if that
+    # call compiles nothing new, the service ran exactly this executable,
+    # with interpret and the oracle off, and its text names the Mosaic
+    # kernel.
     reads, _ = batches[-1]
     bucket = svc.bucket_for(cfg.read_len - cfg.k + 1)
     padded = np.zeros((BATCH, bucket + cfg.k - 1), np.uint8)
     padded[:, :cfg.read_len] = np.stack(reads)
+    padded = jax.numpy.asarray(padded)
     plan = query.plan_query(cfg.idl_config(), cfg.scheme, padded.shape,
                             eng.words.shape, bit_probe=False, lane32=True)
-    rplan, _ = plan.plan_runs(jax.numpy.asarray(padded))
-    n0 = probe_ops._planned_gather._cache_size()
-    probe_ops.gather_planned_rows(eng.words, rplan, interpret=False)
-    reused = probe_ops._planned_gather._cache_size() == n0
-    text = probe_ops._planned_gather.lower(
-        eng.words, jax.numpy.asarray(rplan.block_ids),
-        jax.numpy.asarray(rplan.group_ids), jax.numpy.asarray(rplan.offsets),
-        rows_per_block=rplan.block_bits, probes_per_run=rplan.probes_per_run,
-        n_probes=rplan.n_probes, row_words=cfg.file_words, interpret=False,
-        use_ref=False).compile().as_text()
+    step = query._execute_planned
+    n0 = step._cache_size()
+    _, n_runs, _ = step(eng.words, padded, plan=plan, interpret=False,
+                        use_ref=False)
+    reused = step._cache_size() == n0
+    text = step.lower(eng.words, padded, plan=plan, interpret=False,
+                      use_ref=False).compile().as_text()
     custom = "tpu_custom_call" in text
+    n_probes = BATCH * cfg.eta * plan.n_kmers
     print(f"idl_probe step: served executable reused {reused}, "
           f"tpu_custom_call in its compiled text {custom}; "
-          f"{rplan.n_runs} runs over {rplan.n_probes} probes")
+          f"{int(n_runs)} runs over {n_probes} probes")
     check(reused and custom, "the served idl_probe step is not the kernel")
 
     stats = dev.memory_stats() or {}

@@ -22,7 +22,7 @@ Both data paths route through shared planner/executor layers that treat
 every engine's storage as a packed ``(n_rows, W)`` bit-matrix:
 
 * queries through :mod:`repro.index.query` — backends ``"jnp"`` (pure-XLA
-  gather), ``"idl_probe"`` (host run-length planner + the generalized
+  gather), ``"idl_probe"`` (device run-length planner + the generalized
   Pallas ``probe_rows`` kernel), ``"sharded"`` (``shard_map`` over a 1-D
   device mesh);
 * inserts through :mod:`repro.index.ingest` — backends ``"jnp"`` (one
@@ -139,7 +139,7 @@ class PackedBloomIndex(_StateView):
         """(B, n_kmers) bool per-kmer membership.
 
         ``backend`` picks the shared query executor (see
-        :mod:`repro.index.query`): ``"jnp"``, ``"idl_probe"`` (host
+        :mod:`repro.index.query`): ``"jnp"``, ``"idl_probe"`` (device
         run-length planner + Pallas kernel; kw ``interpret`` forces or
         disables Pallas interpreter mode, defaulting to interpret on CPU;
         kw ``use_ref`` swaps in the kernel's fused jnp oracle) or

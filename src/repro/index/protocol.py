@@ -16,7 +16,7 @@ RAMBO, the bit-sliced serving index) speaks the same four-method protocol:
 * ``query_batch(reads, backend=...)``  — per-kmer membership for a batch.
   Every engine routes through the shared planner/executor layer
   (:mod:`repro.index.query`): ``backend="jnp"`` is the pure-XLA reference,
-  ``backend="idl_probe"`` the host run-length planner + generalized Pallas
+  ``backend="idl_probe"`` the device run-length planner + generalized Pallas
   ``probe_rows`` kernel, ``backend="sharded"`` a ``shard_map`` over a 1-D
   device mesh splitting the words/files axis. All three are bit-identical;
 * ``msmt(reads, theta)``               — Multiple-Set Membership Testing

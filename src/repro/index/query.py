@@ -28,10 +28,12 @@ matrix geometry, the run-coalescing block height — and is built once per
 (:func:`plan_query`). Executing a plan picks one of three backends:
 
 * ``"jnp"``       — pure-XLA reference gather (always available);
-* ``"idl_probe"`` — the host-side run-length planner + the generalized
-  Pallas ``probe_rows`` kernel: probes are run-length-encoded by matrix
+* ``"idl_probe"`` — the run-length planner + the generalized Pallas
+  ``probe_rows`` kernel: probes are run-length-encoded by matrix
   row-block, each run DMAs ONE ``(rows_per_block, W)`` tile, and the whole
-  ``(B, η, n_kmers)`` batch executes as a single kernel launch;
+  ``(B, η, n_kmers)`` batch executes as a single kernel launch. Hash,
+  plan, kernel and the AND over η are one jitted program per plan, so
+  nothing is pulled to the host between the reads and the kernel;
 * ``"sharded"``   — ``shard_map`` over a 1-D device mesh. Bit probes split
   the words axis (each shard resolves its local probes and misses combine
   with a single ``lax.psum``); row probes split the file-words axis (the
@@ -59,6 +61,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
 from typing import NamedTuple, Optional
 
 import jax
@@ -116,6 +119,52 @@ def record_locality(*, scheme: str, op: str, tile_bytes: int, n_runs: int,
 
 
 _LOCALITY_HANDLES: dict = {}
+
+
+class _PendingLocality(NamedTuple):
+    scheme: str
+    block_bytes: int
+    n_runs: jax.Array        # device scalar
+    run_hist: jax.Array      # device (C + 1,) runs of each length 0..C
+
+
+# Locality values of planned query batches, still on the device: the plan
+# is made on the device, so its run count is known to the host only once
+# the batch's program has run. Appended at dispatch, taken off by
+# :func:`drain_locality`.
+_PENDING: list = []
+_PENDING_LOCK = threading.Lock()
+
+
+def drain_locality() -> int:
+    """Record the locality counters of every planned query batch whose
+    program has finished, with one ``jax.device_get``; batches still
+    running stay queued, so this never waits for the device. Returns the
+    batches recorded.
+
+    The serving layer calls it once a batch's answer is on the host (the
+    answer comes from the same program as the batch's plan, so the batch
+    is recorded before its answers are handed out); the planned backend
+    calls it before each dispatch, which bounds the queue for callers
+    that never drain."""
+    with _PENDING_LOCK:
+        ready = [e.n_runs.is_ready() and e.run_hist.is_ready()
+                 for e in _PENDING]
+        done = [e for e, r in zip(_PENDING, ready) if r]
+        if not done:
+            return 0
+        _PENDING[:] = [e for e, r in zip(_PENDING, ready) if not r]
+        got = jax.device_get([(e.n_runs, e.run_hist) for e in done])
+        for e, (n_runs, hist) in zip(done, got):
+            n_runs = int(n_runs)
+            lengths = np.arange(hist.shape[0])
+            record_locality(
+                scheme=e.scheme, op="query",
+                tile_bytes=n_runs * e.block_bytes, n_runs=n_runs,
+                n_probes=int(lengths @ hist),
+                run_lengths=np.repeat(lengths[1:], hist[1:]))
+        return len(done)
+
 
 # Feed the run-length histogram from every Nth batch (1 = every batch).
 # The first batch after a reset always lands (count % N == 1), so short
@@ -237,23 +286,23 @@ class QueryPlan:
         return (locs >> jnp.uint32(5)) if self.bit_probe else locs
 
     def plan_runs(self, reads: jax.Array):
-        """Host-side run-length plan for the whole batch (one kernel launch).
+        """The batch's run plan, made on the host by the numpy planner: the
+        oracle of the plan the ``idl_probe`` backend makes on the device
+        (tests and offline locality counts; nothing served calls it).
 
         Returns ``(ProbePlan, locs)`` where locs is the (B, η, n_kmers)
         numpy location array the plan was built from.
         """
         from repro.kernels.idl_probe import ops as probe_ops
 
-        with obs_trace.stage("plan.locations"):
-            locs = np.asarray(self.locations(reads))
+        locs = np.asarray(self.locations(reads))
         rows = (locs >> 5) if self.bit_probe else locs
         b, eta, n_k = locs.shape
-        with obs_trace.stage("plan.runs"):
-            rplan = probe_ops.plan_probe_runs(
-                rows.reshape(b * eta, n_k),
-                block_bits=self.rows_per_block,
-                probes_per_run=self.probes_per_run,
-            )
+        rplan = probe_ops.plan_probe_runs(
+            rows.reshape(b * eta, n_k),
+            block_bits=self.rows_per_block,
+            probes_per_run=self.probes_per_run,
+        )
         return rplan, locs
 
     def run_dma_bytes(self, rplan) -> int:
@@ -302,26 +351,19 @@ class QueryPlan:
         )
 
     def _execute_idl_probe(self, matrix, reads, interpret, use_ref):
-        from repro.kernels.idl_probe import ops as probe_ops
-
         if interpret is None:
             interpret = jax.default_backend() == "cpu"
-        # the host run planner, from the locations through the kernel's
-        # (asynchronous) launch
+        drain_locality()          # earlier batches', never this one's
+        # the plan is made on the device: the host's part is the dispatch
         with obs_trace.stage("plan"):
-            rplan, locs = self.plan_runs(reads)
-            record_locality(
-                scheme=self.scheme, op="query",
-                tile_bytes=self.run_dma_bytes(rplan), n_runs=rplan.n_runs,
-                n_probes=int(rplan.n_probes), run_lengths=rplan.run_lengths)
-            gathered = probe_ops.gather_planned_rows(
-                matrix, rplan, interpret=interpret, use_ref=use_ref,
-            )                                       # (n_probes, W)
-        b, eta, n_k = locs.shape
-        gathered = gathered.reshape(b, eta, n_k, self.row_words)
-        return _finish_probe(
-            gathered, jnp.asarray(locs), bit_probe=self.bit_probe
-        )
+            vals, n_runs, run_hist = _execute_planned(
+                matrix, reads, plan=self, interpret=interpret,
+                use_ref=use_ref)
+            if obs_metrics.DEFAULT.enabled:
+                with _PENDING_LOCK:
+                    _PENDING.append(_PendingLocality(
+                        self.scheme, self.block_bytes, n_runs, run_hist))
+        return vals
 
     def _execute_sharded(self, matrix, reads, mesh):
         if mesh is None:
@@ -471,6 +513,31 @@ def _finish_probe(rows: jax.Array, locs: jax.Array, *, bit_probe: bool):
     else:
         vals = rows
     return jax.lax.reduce(vals, _FULL, jax.lax.bitwise_and, dimensions=(1,))
+
+
+@functools.partial(jax.jit, static_argnames=("plan", "interpret",
+                                             "use_ref"))
+def _execute_planned(matrix: jax.Array, reads: jax.Array, *,
+                     plan: QueryPlan, interpret: bool, use_ref: bool):
+    """The ``idl_probe`` step: hash, plan the runs on the device, run the
+    kernel (or its oracle) and AND over η, in one program. Returns
+    ``(values, n_runs, run_hist)``: the last two feed the locality
+    counters."""
+    from repro.kernels.idl_probe import ops as probe_ops
+
+    locs = plan.locations(reads)
+    rows = plan.row_indices(locs).astype(jnp.int32).reshape(-1)
+    runs = probe_ops.device_plan_runs(
+        rows, rows_per_block=plan.rows_per_block,
+        probes_per_run=plan.probes_per_run)
+    got = probe_ops._planned_gather(
+        matrix, runs.block_ids, runs.group_ids, runs.offsets, runs.n_runs,
+        rows_per_block=plan.rows_per_block,
+        probes_per_run=plan.probes_per_run, n_probes=rows.shape[0],
+        row_words=plan.row_words, interpret=interpret, use_ref=use_ref)
+    got = got.reshape(locs.shape + (plan.row_words,))
+    return (_finish_probe(got, locs, bit_probe=plan.bit_probe),
+            runs.n_runs, runs.run_hist)
 
 
 @functools.partial(jax.jit, static_argnames=("plan",))

@@ -3,14 +3,17 @@
 The TPU translation of the paper's cache-locality argument (DESIGN.md §2):
 
 * The BF lives packed (uint32 words) in HBM — far too big for VMEM.
-* The host scheduler (ops.plan_probe_runs) run-length-encodes the probe
-  stream by block. IDL makes runs long when the block covers the locality
-  window; RH makes every probe its own run.
+* The run planner (ops.device_plan_runs, on the device, in the kernel's
+  program) run-length-encodes the probe stream by block. IDL makes runs
+  long when the block covers the locality window; RH makes every probe
+  its own run.
 * Grid = one step per run. A scalar-prefetch array holds each run's block
   id; the BlockSpec index_map consumes it, so Pallas DMAs exactly ONE tile
   from HBM per run and double-buffers the next tile while the current one
   is probed. HBM traffic = n_runs × block_bytes — the quantity IDL
-  minimizes.
+  minimizes. The plan's arrays are sized for the worst case (a run a
+  probe); the grid is as long as the plan's run count, a device value,
+  so the pad runs past it cost no step.
 * Within a resident tile the gather is done MXU-natively with one-hot
   matmuls — no scalar loads, no unsupported vector gathers.
 
@@ -112,6 +115,7 @@ def probe_rows(
     block_ids: jax.Array,    # (R,) int32 row-block id per run
     group_ids: jax.Array,    # (R,) int32 output group per run (nondecreasing)
     offsets: jax.Array,      # (R, C) int32 row offset in block, -1 elsewhere
+    n_runs: jax.Array,       # () int32 runs of the plan; the rest are pad
     *,
     rows_per_block: int,
     probes_per_run: int,
@@ -126,6 +130,8 @@ def probe_rows(
     holds the probes of one block within one group. Returns
     ``(W, n_groups * C)`` uint32: the W-word row of every probe, in probe
     order, transposed like the matrix. ``R`` must be a multiple of 8.
+    The grid stops after the first ``n_runs`` runs (a grid sized on the
+    device), so the pad runs that fill ``R`` take no step.
     """
     r = block_ids.shape[0]
     c = probes_per_run
@@ -147,10 +153,12 @@ def probe_rows(
                              lambda i, bid, gid: (0, bid[i]))
     group_spec = pl.BlockSpec((w, c), lambda i, bid, gid: (0, gid[i]))
     out = jnp.zeros((w, n_groups * c), jnp.uint32)
+    n_runs = jnp.asarray(n_runs, jnp.int32)
     for part in lanes.chunks(r):
+        steps = jnp.clip(n_runs - part.start, 0, part.stop - part.start)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(part.stop - part.start,),
+            grid=(steps,),
             in_specs=[lane_spec, tile_spec, group_spec],
             out_specs=group_spec,
         )

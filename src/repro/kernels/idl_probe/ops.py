@@ -1,16 +1,20 @@
-"""jit wrapper + host-side probe planner for the IDL probe kernel.
+"""jit wrapper and run planners for the IDL probe kernel.
 
-The planner is the "scheduler" half of the design (vLLM-style): it
-run-length-encodes the probe stream by BF block and emits fixed-shape,
-kernel-ready arrays. It runs on the host (numpy) exactly like a serving
-scheduler builds block tables — data-dependent sizing lives here, the device
-kernel sees static shapes.
+The planner run-length-encodes the probe stream by row block and emits the
+fixed-shape arrays the kernel takes. The served planner,
+:func:`device_plan_runs`, runs on the device inside the same jitted program
+as the hash and the kernel, so a batch makes no host round trip between its
+reads and the kernel's launch. :func:`plan_probe_runs` is its numpy twin,
+which nothing served calls: the oracle the tests hold the device planner
+to, and the planner of the original flat-BF kernel (:func:`probe_membership`,
+run only interpreted, by the tests).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +22,6 @@ import numpy as np
 
 from repro.kernels import lanes
 from repro.kernels.idl_probe import kernel, ref
-from repro.obs import trace as obs_trace
 
 
 @dataclasses.dataclass
@@ -46,7 +49,8 @@ class ProbePlan:
 def plan_probe_runs(
     locs: np.ndarray, block_bits: int, probes_per_run: int = 128
 ) -> ProbePlan:
-    """Run-length-encode (P, n) probe streams into block-resident runs.
+    """Run-length-encode (P, n) probe streams into block-resident runs, on
+    the host (the numpy oracle of :func:`device_plan_runs`).
 
     ``locs`` may be bit locations (``block_bits`` = bits per block, the
     original flat-BF use) or matrix row indices (``block_bits`` = rows per
@@ -132,39 +136,16 @@ def probe_membership(
     return scatter_and_reduce(bits, plan)
 
 
-def gather_planned_rows(
-    matrix: jax.Array, plan: ProbePlan, *, interpret: bool,
-    use_ref: bool = False,
-) -> jax.Array:
-    """Execute a row plan; return (n_probes, W) uint32 rows in probe order.
-
-    ``plan.block_bits`` is interpreted as rows-per-block. ``use_ref`` swaps
-    the Pallas kernel for the pure-jnp oracle — same plan, same result;
-    the executor on hosts without a Mosaic target.
-    """
-    with obs_trace.stage("plan.upload"):
-        runs = (jnp.asarray(plan.block_ids), jnp.asarray(plan.group_ids),
-                jnp.asarray(plan.offsets))
-    return _planned_gather(
-        matrix, *runs,
-        rows_per_block=plan.block_bits,
-        probes_per_run=plan.probes_per_run,
-        n_probes=plan.n_probes,
-        row_words=int(matrix.shape[-1]) if matrix.ndim > 1 else 1,
-        interpret=interpret,
-        use_ref=use_ref,
-    )
-
-
 @functools.partial(jax.jit, static_argnames=(
     "rows_per_block", "probes_per_run", "n_probes", "row_words",
     "interpret", "use_ref",
 ))
-def _planned_gather(matrix, bids, gids, offs, *, rows_per_block,
+def _planned_gather(matrix, bids, gids, offs, n_runs, *, rows_per_block,
                     probes_per_run, n_probes, row_words, interpret, use_ref):
-    """One fused call: run the kernel (or ref) and cut the groups' padding.
-    The kernel takes and returns the transposed view (see
-    ``repro.kernels.lanes``)."""
+    """Execute a row plan; return ``(n_probes, W)`` uint32 rows in probe
+    order. ``use_ref`` swaps the Pallas kernel for its pure-jnp oracle
+    (same plan, same result). The kernel takes and returns the transposed
+    view (see ``repro.kernels.lanes``)."""
     matrix = jnp.reshape(matrix, (-1, row_words))
     n_groups = -(-n_probes // probes_per_run)
     if use_ref:
@@ -173,11 +154,79 @@ def _planned_gather(matrix, bids, gids, offs, *, rows_per_block,
             n_groups=n_groups)
     else:
         rows = kernel.probe_rows(
-            matrix.T, bids, gids, offs, rows_per_block=rows_per_block,
+            matrix.T, bids, gids, offs, n_runs,
+            rows_per_block=rows_per_block,
             probes_per_run=probes_per_run, n_groups=n_groups,
             interpret=interpret,
         ).T
     return rows[:n_probes]
+
+
+class DevicePlan(NamedTuple):
+    """The run plan of one probe stream, as device arrays."""
+
+    block_ids: jax.Array   # (R,) int32 block per run
+    group_ids: jax.Array   # (R,) int32 output group per run
+    offsets: jax.Array     # (R, C) int32 offset in block, -1 off the run
+    n_runs: jax.Array      # () int32 true run count
+    run_hist: jax.Array    # (C + 1,) int32 runs of each length 0..C (the
+                           # pad runs are the length-0 ones)
+
+
+@functools.partial(jax.jit, static_argnames=("rows_per_block",
+                                             "probes_per_run"))
+def device_plan_runs(rows: jax.Array, *, rows_per_block: int,
+                     probes_per_run: int) -> DevicePlan:
+    """Run-length-encode the ``(N,)`` probe stream ``rows`` on the device.
+
+    The plan :func:`plan_probe_runs` makes, run for run: probe ``p`` is
+    lane ``p % C`` of group ``p // C``, a run is a stretch of probes of
+    one block within one group, and pad runs repeat the last run's block
+    and group with offsets of -1. Without a host sync the run count
+    cannot size the arrays, so ``R`` is ``N`` rounded up to
+    ``lanes.RUNS_PER_BLOCK`` (a run holds at least one probe); the
+    kernel's grid stops at ``n_runs``, so the pad runs take no step.
+
+    No sort, no binary search and no gather of single elements (each
+    costs the TPU a pass of its own): runs are numbered by a cumsum of
+    start flags, so run ids are nondecreasing along the stream and within
+    each group, and a group's first lane starts a run. A run finds its
+    group by a cumsum over marks at the groups' first run ids (a scatter
+    of one element a group), and its lanes, offsets and block in that
+    group's row of the stream.
+    """
+    n = rows.shape[0]
+    c = probes_per_run
+    r = -(-n // lanes.RUNS_PER_BLOCK) * lanes.RUNS_PER_BLOCK
+    g = -(-n // c)
+    rows = rows.astype(jnp.int32)
+    blocks = rows // rows_per_block
+    idx = jnp.arange(n, dtype=jnp.int32)
+    start = (idx % c == 0) | (blocks != jnp.roll(blocks, 1))
+    run = jnp.cumsum(start.astype(jnp.int32), dtype=jnp.int32) - 1
+    # (G, C) views of the stream; lanes past its end carry the run id R,
+    # which no run has
+    tail = g * c - n
+    run2 = jnp.pad(run, (0, tail), constant_values=r).reshape(g, c)
+    row2 = jnp.pad(rows, (0, tail)).reshape(g, c)
+
+    rid = jnp.arange(r, dtype=jnp.int32)
+    group_first = jnp.zeros((r,), jnp.int32).at[run2[:, 0]].set(
+        1, indices_are_sorted=True, unique_indices=True)
+    gid = jnp.cumsum(group_first, dtype=jnp.int32) - 1  # pad runs: G - 1
+    mine = run2[gid] == rid[:, None]                    # (R, C)
+    lane_row = row2[gid]
+    lane_block = lane_row // rows_per_block
+    offsets = jnp.where(mine, lane_row - lane_block * rows_per_block, -1)
+    length = jnp.sum(mine, axis=1, dtype=jnp.int32)
+    block_ids = jnp.where(
+        length > 0, jnp.max(jnp.where(mine, lane_block, -1), axis=1),
+        blocks[-1])
+    run_hist = jnp.sum(
+        length[:, None] == jnp.arange(c + 1, dtype=jnp.int32), axis=0,
+        dtype=jnp.int32)
+    return DevicePlan(block_ids=block_ids, group_ids=gid,
+                      offsets=offsets, n_runs=run[-1] + 1, run_hist=run_hist)
 
 
 def scatter_and_reduce(bits: jax.Array, plan: ProbePlan) -> jax.Array:

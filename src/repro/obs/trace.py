@@ -26,7 +26,8 @@ An open :class:`Span` object is only held where someone must be able to
 death closes them instead of leaking them).
 
 Stages (:class:`stage`) are the other half: batch-level intervals of the
-serving pipeline and the host run planner, timed where the work happens.
+serving pipeline and the query planner's dispatch, timed where the work
+happens.
 Each one is a ``jax.profiler.TraceAnnotation``, so a profiler session
 records it on its host plane on the same clock as the device's ops, and
 its duration lands in the registry histogram ``serving.stage_ms``
@@ -277,9 +278,9 @@ def set_enabled(enabled: bool) -> None:
 
 
 class stage:
-    """Time one stage of the serving pipeline or the host run planner::
+    """Time one stage of the serving pipeline or the query planner::
 
-        with trace.stage("plan.runs"):
+        with trace.stage("plan"):
             ...
 
     On entry it opens ``jax.profiler.TraceAnnotation(name)``, so a profiler

@@ -17,13 +17,13 @@ This module gives it a real event loop:
   hostage to batching).
 
 * **Double-buffered pipeline** — the flusher runs the *host* half of a
-  batch (padding, thresholds, the ``idl_probe`` backend's per-batch probe
-  planning) and dispatches the device step, then immediately starts on
-  the next batch while a completer thread blocks on the previous batch's
-  device output, decodes verdicts and resolves futures. The bounded
-  hand-off queue (``pipeline_depth``) is the double buffer: host planning
-  for batch N+1 overlaps device execution of batch N, and backpressure
-  stops a fast submitter from piling up unbounded device work.
+  batch (padding, thresholds) and dispatches the device step, then
+  immediately starts on the next batch while a completer thread blocks on
+  the previous batch's device output, decodes verdicts and resolves
+  futures. The bounded hand-off queue (``pipeline_depth``) is the double
+  buffer: host work for batch N+1 overlaps device execution of batch N
+  (the device queues batch N+1 behind it), and backpressure stops a fast
+  submitter from piling up unbounded device work.
 
 All three stages call the SAME ``_assemble`` / ``_execute`` / ``_finalize``
 methods the synchronous ``flush()`` path uses, so scheduler answers are
@@ -517,9 +517,9 @@ class AsyncScheduler:
             if writes:
                 self._apply_writes(writes)
                 continue
-            # host + dispatch, outside the lock: assemble the padded batch,
-            # run per-batch host planning (idl_probe) and launch the device
-            # step; with async dispatch the completer owns the blocking wait
+            # host + dispatch, outside the lock: assemble the padded batch
+            # and launch the device step; with async dispatch the
+            # completer owns the blocking wait
             try:
                 pairs = [(p.request, p.n_kmers) for p in take]
                 t0 = time.monotonic()

@@ -386,8 +386,9 @@ class GeneSearchService:
 
         ``"jnp"`` jits the whole step end-to-end (the state is a pytree
         argument, so the index matrices are real inputs, not baked-in
-        constants). The host-planned backends (``idl_probe`` / ``sharded``)
-        run the probe eagerly and jit only the coverage postlude.
+        constants). The ``idl_probe`` and ``sharded`` backends dispatch
+        the probe through their own jitted executors (one per plan) and
+        jit the coverage postlude here.
         """
         r = self._runners.get(bucket)
         if r is not None:
@@ -563,6 +564,9 @@ class GeneSearchService:
         with obs_trace.stage("serve.finalize"):
             with obs_trace.stage("serve.finalize.wait"):
                 out = np.asarray(out)          # blocks until device done
+            # the planned backend's locality counters of this batch, which
+            # the device produced with the answer
+            query.drain_locality()
             single_set = self._state.meta.engine == "bloom"
             results = []
             for i, (req, n_k) in enumerate(take):
@@ -624,8 +628,8 @@ class GeneSearchService:
         """Compiled-executable count per bucket (the compile-once proof).
 
         For the ``jnp`` backend this counts the end-to-end jitted step; for
-        planned backends, the jitted coverage postlude (the probe itself is
-        host-planned per batch by design).
+        the others, the jitted coverage postlude (the probe is the
+        backend's own executor, compiled once per plan).
         """
         return {b: counter._cache_size()
                 for b, (_, counter) in sorted(self._runners.items())}

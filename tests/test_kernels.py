@@ -82,9 +82,15 @@ class TestProbeKernel:
         rows[1].sort()  # one stream with long block runs, two scattered
         plan = probe_ops.plan_probe_runs(rows, block_bits=rpb,
                                          probes_per_run=C)
-        got = probe_ops.gather_planned_rows(matrix, plan, interpret=True)
-        got_ref = probe_ops.gather_planned_rows(matrix, plan, interpret=False,
-                                                use_ref=True)
+        def gather(**kw):
+            return probe_ops._planned_gather(
+                matrix, jnp.asarray(plan.block_ids),
+                jnp.asarray(plan.group_ids), jnp.asarray(plan.offsets),
+                jnp.int32(plan.n_runs), rows_per_block=rpb,
+                probes_per_run=C, n_probes=plan.n_probes, row_words=w, **kw)
+
+        got = gather(interpret=True, use_ref=False)
+        got_ref = gather(interpret=False, use_ref=True)
         want = np.asarray(matrix)[rows.reshape(-1)]
         np.testing.assert_array_equal(np.asarray(got), want)
         np.testing.assert_array_equal(np.asarray(got_ref), want)

@@ -390,9 +390,9 @@ class TestExport:
 # ---------------------------------------------------------------------------
 
 # every stage the idl_probe query path times, and the stage holding each
+# (``plan`` is the dispatch of the step that plans the runs on the device)
 STAGES = {"serve.assemble": None, "serve.execute": None,
-          "plan": "serve.execute", "plan.locations": "plan",
-          "plan.runs": "plan", "plan.upload": "plan",
+          "plan": "serve.execute",
           "serve.finalize": None, "serve.finalize.wait": "serve.finalize"}
 
 

@@ -54,6 +54,7 @@ def shapes(one_chip):
         "matrix_t": spec((CFG.file_words, CFG.m), jnp.uint32),
         "ids": spec((RUNS,), jnp.int32),
         "lanes": spec((RUNS, C), jnp.int32),
+        "count": spec((), jnp.int32),
     }
 
 
@@ -87,11 +88,11 @@ def test_probe_rows_compiles_for_v5e(shapes, no_compile_cache):
     rpb = _rows_per_block()
     n_groups = RUNS // 2
     compiled = jax.jit(
-        lambda m, b, g, o: probe_kernel.probe_rows(
-            m, b, g, o, rows_per_block=rpb, probes_per_run=C,
+        lambda m, b, g, o, n: probe_kernel.probe_rows(
+            m, b, g, o, n, rows_per_block=rpb, probes_per_run=C,
             n_groups=n_groups, interpret=False)
     ).lower(shapes["matrix_t"], shapes["ids"], shapes["ids"],
-            shapes["lanes"]).compile()
+            shapes["lanes"], shapes["count"]).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     # the (W, n_rows) view is lane-dense: the matrix goes in as 8 GiB,
@@ -109,12 +110,12 @@ def test_probe_rows_custom_call_is_named(one_chip, no_compile_cache):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     runs = 2 * lanes.RUNS_PER_BLOCK
     compiled = jax.jit(
-        lambda m, b, g, o: probe_kernel.probe_rows(
-            m, b, g, o, rows_per_block=512, probes_per_run=C,
+        lambda m, b, g, o, n: probe_kernel.probe_rows(
+            m, b, g, o, n, rows_per_block=512, probes_per_run=C,
             n_groups=runs, interpret=False)
     ).lower(spec((CFG.file_words, 1 << 16), jnp.uint32),
             spec((runs,), jnp.int32), spec((runs,), jnp.int32),
-            spec((runs, C), jnp.int32)).compile()
+            spec((runs, C), jnp.int32), spec((), jnp.int32)).compile()
     calls = [line for line in compiled.as_text().splitlines()
              if "custom_call_target=\"tpu_custom_call\"" in line]
     assert calls
@@ -134,3 +135,31 @@ def test_insert_runs_compiles_for_v5e(shapes, no_compile_cache):
     # updated in place: the output is the donated matrix itself
     assert mem.alias_size_in_bytes == 8 * 2**30
     assert mem.temp_size_in_bytes < 2**30
+
+
+def test_served_probe_step_compiles_for_v5e(one_chip, no_compile_cache):
+    """The whole ``idl_probe`` step of a screen batch (256 reads of 286
+    bases: hash, device run planner, kernel, AND over eta) compiles as one
+    program, with the 8 GiB matrix in place and the plan's temporaries,
+    the 128 MB (R, C) offsets among them, under 1 GiB."""
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    n_reads, read_len = 256, 256 + CFG.k - 1
+    plan = query.plan_query(
+        CFG.idl_config(), CFG.scheme, (n_reads, read_len),
+        (CFG.m, CFG.file_words), bit_probe=False, lane32=True,
+        probes_per_run=C)
+    compiled = query._execute_planned.lower(
+        spec((CFG.m, CFG.file_words), jnp.uint32),
+        spec((n_reads, read_len), jnp.uint8),
+        plan=plan, interpret=False, use_ref=False).compile()
+    text = compiled.as_text()
+    n_probes = n_reads * CFG.eta * 256
+    assert n_probes == RUNS                 # R = the batch's probe count
+    assert "tpu_custom_call" in text
+    assert f"s32[{n_probes},{C}]" in text   # the offsets, on the device
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 8 * 2**30 + 2**28
+    assert n_probes * C * 4 == 2**27
+    assert mem.temp_size_in_bytes < 2**30
+    assert mem.output_size_in_bytes < n_reads * 256 * CFG.file_words * 4 + 2**12
