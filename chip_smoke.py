@@ -15,7 +15,8 @@ that the served ``idl_probe`` step is the compiled kernel
 (``tpu_custom_call``), not its jnp oracle.
 
 Four chips: 4096 files over a 4-device files-axis mesh, 8 GiB per chip.
-Each device builds its own 1024-file shard; the ``sharded`` query backend
+``repro.launch.serve.build_sharded_index`` builds each 1024-file shard on
+its own device and joins them; the ``sharded`` query backend
 serves the whole archive, and its answers must equal those of the plain
 reference, the ``jnp`` backend on each shard alone, concatenated over
 files. No device may hold more than its shard.
@@ -186,26 +187,26 @@ def one_chip(args, jax, cfg, clock) -> None:
 
 
 def four_chips(args, jax, cfg, clock) -> None:
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    import dataclasses
 
     from repro.data import genome
-    from repro.index import BitSlicedIndex, query
-    from repro.launch.serve import build_index
+    from repro.index import BitSlicedIndex
+    from repro.launch.serve import build_sharded_index
     from repro.serving import GeneSearchService, ServiceConfig
 
     devs = jax.devices()[:4]
     n_files = cfg.n_files * len(devs)     # each chip holds one config
     archive = genome.synth_archive(n_files, args.genome_len, seed=args.seed)
     t0 = time.perf_counter()
-    shards = []
-    for d, dev in enumerate(devs):
-        base = d * cfg.n_files
-        mine = [(f.file_id - base, f.genome)
-                for f in archive[base:base + cfg.n_files]]
-        with jax.default_device(dev):
-            shards.append(build_index(cfg, mine, chunk_reads=256))
-    jax.block_until_ready([s.words for s in shards])
+    whole = build_sharded_index(dataclasses.replace(cfg, n_files=n_files),
+                                archive, devs, chunk_reads=256)
+    jax.block_until_ready(whole.words)
     build_s = time.perf_counter() - t0
+    # the shards in file order, each the index of its own files
+    shards = [BitSlicedIndex(cfg=whole.cfg, scheme=whole.scheme,
+                             n_files=cfg.n_files, words=s.data)
+              for s in sorted(whole.words.addressable_shards,
+                              key=lambda s: s.index[1].start)]
     shard_bytes = shards[0].state.nbytes
     for d, s in enumerate(shards):
         check(s.words.devices() == {devs[d]},
@@ -213,16 +214,9 @@ def four_chips(args, jax, cfg, clock) -> None:
     print(f"build: {len(devs)} shards of {cfg.n_files} files, each "
           f"{gib(shard_bytes)} on its own device, {build_s:.3f} s with "
           f"idl_insert")
-
-    mesh = query.default_mesh()
-    words = jax.make_array_from_single_device_arrays(
-        (cfg.m, cfg.file_words * len(devs)),
-        NamedSharding(mesh, P(None, query.MESH_AXIS)),
-        [s.words for s in shards])
-    whole = BitSlicedIndex(cfg=cfg.idl_config(), scheme=cfg.scheme,
-                           n_files=n_files, words=words)
-    print(f"state: {words.shape} uint32 sharded {words.sharding.spec} over "
-          f"{len(devs)} devices, {gib(whole.state.nbytes)}")
+    print(f"state: {whole.words.shape} uint32 sharded "
+          f"{whole.words.sharding.spec} over {len(devs)} devices, "
+          f"{gib(whole.state.nbytes)}")
 
     batches = read_batches(archive, N_BATCHES, cfg.read_len,
                            np.random.default_rng(args.seed + 1))

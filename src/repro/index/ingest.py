@@ -408,7 +408,7 @@ def _engine_k(index) -> int:
     return int(k)
 
 
-def _file_sequences(item, default_id: int):
+def file_sequences(item, default_id: int):
     """Normalize an archive item to (file_id, [code arrays])."""
     from repro.data import genome as genome_mod
 
@@ -475,7 +475,7 @@ def build_archive(
             )
 
     for pos, item in enumerate(files):
-        fid, seqs = _file_sequences(item, pos)
+        fid, seqs = file_sequences(item, pos)
         for codes in seqs:
             windows = genome_mod.window_reads(codes, read_len, k)
             if windows.shape[0] == 0:
@@ -528,7 +528,7 @@ def build_sharded_archive(
     spec, parts = shards_mod.partition_state(index, n_shards)
     items = []
     for pos, item in enumerate(files):
-        fid, seqs = _file_sequences(item, pos)
+        fid, seqs = file_sequences(item, pos)
         items.extend((fid, codes) for codes in seqs)
     build_kw = dict(read_len=read_len, chunk_reads=chunk_reads,
                     window_min=window_min, pad_final=pad_final)

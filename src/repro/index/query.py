@@ -67,7 +67,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import idl as idl_mod
 from repro.index import packed
@@ -367,9 +367,9 @@ class QueryPlan:
 
     def _execute_sharded(self, matrix, reads, mesh):
         if mesh is None:
-            mesh = default_mesh()
-        fn = _sharded_executor(self, mesh)
-        return fn(matrix, reads)
+            mesh = mesh_of(matrix)
+        with obs_trace.stage("shard.gather"):
+            return _sharded_executor(self, mesh)(matrix, reads)
 
     def _execute_dedup(self, matrix, reads, *, backend, interpret,
                        use_ref, mesh):
@@ -552,6 +552,19 @@ def _execute_jnp(matrix: jax.Array, reads: jax.Array, *, plan: QueryPlan):
 def default_mesh() -> Mesh:
     """1-D mesh over every visible device (the scale-out words/files axis)."""
     return Mesh(np.asarray(jax.devices()), (MESH_AXIS,))
+
+
+def mesh_of(matrix) -> Mesh:
+    """The mesh the ``sharded`` backend serves ``matrix`` on: the one that
+    already holds it, when it is sharded by name over a 1-D
+    ``MESH_AXIS`` mesh (a state split over some of the host's devices,
+    as ``launch.serve.build_sharded_index`` places it), so that no
+    dispatch moves it; otherwise :func:`default_mesh`."""
+    sharding = getattr(matrix, "sharding", None)
+    if isinstance(sharding, NamedSharding) and isinstance(
+            sharding.mesh, Mesh) and sharding.mesh.axis_names == (MESH_AXIS,):
+        return sharding.mesh
+    return default_mesh()
 
 
 # Bounded like the plan cache — but note the asymmetry: evicting an

@@ -26,10 +26,11 @@ import time
 
 import jax
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import repro.configs as configs
 from repro.data import genome
-from repro.index import BitSlicedIndex, ingest
+from repro.index import BitSlicedIndex, ingest, query
 from repro.launch.compile_cache import use_compile_cache
 from repro.serving import GeneSearchService, ServiceConfig
 
@@ -44,6 +45,47 @@ def build_index(cfg, archive, *, chunk_reads: int = 64) -> BitSlicedIndex:
     return ingest.build_archive(eng, archive, read_len=cfg.read_len,
                                 chunk_reads=chunk_reads, backend="idl_insert",
                                 use_ref=jax.default_backend() == "cpu")
+
+
+def build_sharded_index(cfg, archive, devices, *,
+                        chunk_reads: int = 64) -> BitSlicedIndex:
+    """The bit-sliced index over ``archive``, split by files over
+    ``devices``: one index of ``cfg.n_files`` files whose ``words`` are
+    sharded on the file-words axis over a 1-D mesh of exactly those
+    devices, in their order.
+
+    With ``F = cfg.n_files / len(devices)`` (a multiple of 32, so every
+    shard holds whole words), shard ``d`` holds files ``d*F .. (d+1)*F - 1``
+    and all ``m`` rows of their columns. It is built on ``devices[d]`` by
+    :func:`build_index`, its files renumbered from 0, and the shards are
+    joined without a copy. ``archive`` items are whatever
+    :func:`repro.index.ingest.build_archive` takes; file ids in answers are
+    the global ones. The ``sharded`` query backend serves the result on the
+    mesh that holds it."""
+    n = len(devices)
+    per = cfg.n_files // n
+    if per * n != cfg.n_files or per % 32:
+        raise ValueError(
+            f"{cfg.n_files} files do not split into {n} shards of whole "
+            f"32-file words")
+    parts: list = [[] for _ in range(n)]
+    for pos, item in enumerate(archive):
+        fid, seqs = ingest.file_sequences(item, pos)
+        if not 0 <= fid < cfg.n_files:
+            raise ValueError(f"file id {fid} is not in [0, {cfg.n_files})")
+        parts[fid // per].extend((fid % per, s) for s in seqs)
+    shard_cfg = dataclasses.replace(cfg, n_files=per)
+    shards = []
+    for dev, files in zip(devices, parts):
+        with jax.default_device(dev):
+            shards.append(build_index(shard_cfg, files,
+                                      chunk_reads=chunk_reads).words)
+    mesh = Mesh(np.asarray(devices), (query.MESH_AXIS,))
+    words = jax.make_array_from_single_device_arrays(
+        (cfg.m, cfg.n_files // 32),
+        NamedSharding(mesh, P(None, query.MESH_AXIS)), shards)
+    return BitSlicedIndex(cfg=cfg.idl_config(), scheme=cfg.scheme,
+                          n_files=cfg.n_files, words=words)
 
 
 def main() -> None:
