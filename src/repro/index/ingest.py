@@ -285,8 +285,8 @@ def plan_insert(
 
     ``rows_per_block`` defaults to the IDL locality window ``cfg.L``
     translated to matrix rows (``L/32`` packed words for ``"bits"``; for
-    row/column targets, ``L`` rows clamped like the query planner's), as
-    a power of two that divides ``n_rows``.
+    row/column targets, ``L`` rows clamped to a 2 MB bit image of the
+    tile), as a power of two that divides ``n_rows``.
     ``inserts_per_run`` defaults to the TPU lane width (128); 32 on a CPU
     host, where narrow runs waste fewer pad lanes.
     """
@@ -299,7 +299,8 @@ def plan_insert(
         if kind == "bits":
             target = max(cfg.L // 32, 1)
         else:
-            # the query planner's bound (see query.plan_query)
+            # 2^21 / (W*128) rows: a 2 MB f32 bit image of the tile, the
+            # bound the probe kernel once had and the insert kernel keeps
             target = max(1, min(cfg.L, (1 << 21) // max(row_words * 128, 1)))
         rows_per_block = query._pow2_block(n_rows, target)
     if n_rows % rows_per_block:

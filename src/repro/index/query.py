@@ -30,8 +30,9 @@ matrix geometry, the run-coalescing block height — and is built once per
 * ``"jnp"``       — pure-XLA reference gather (always available);
 * ``"idl_probe"`` — the run-length planner + the generalized Pallas
   ``probe_rows`` kernel: probes are run-length-encoded by matrix
-  row-block, each run DMAs ONE ``(rows_per_block, W)`` tile, and the whole
-  ``(B, η, n_kmers)`` batch executes as a single kernel launch. Hash,
+  row-block, each run copies ONE ``(rows_per_block, W)`` tile, and the
+  whole ``(B, η, n_kmers)`` batch executes as one kernel launch per 65536
+  probes (one grid step per group of C probes). Hash,
   plan, kernel and the AND over η are one jitted program per plan, so
   nothing is pulled to the host between the reads and the kernel;
 * ``"sharded"``   — ``shard_map`` over a 1-D device mesh. Bit probes split
@@ -71,6 +72,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import idl as idl_mod
 from repro.index import packed
+from repro.kernels import lanes
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
@@ -438,26 +440,19 @@ def plan_query(
 ) -> QueryPlan:
     """Build (or fetch) the cached plan for one query geometry.
 
-    ``rows_per_block`` defaults to the IDL locality window ``cfg.L``
-    translated to matrix rows (``L/32`` packed words for bit probes, ``L``
-    rows for row probes), clamped to a power of two that divides
-    ``n_rows``. ``probes_per_run`` defaults to the TPU lane width
+    ``rows_per_block`` defaults to the smallest tile the probe kernel can
+    copy: the kernel sees the matrix transposed, ``(W, n_rows)``, tiled
+    ``(8, 128)`` in HBM, so a block is one lane tile of 128 rows (the
+    largest power of two up to that which divides ``n_rows``), whatever
+    the probe kind. ``probes_per_run`` defaults to the TPU lane width
     (128); on a CPU host 32 — narrower runs waste fewer pad lanes where
     there is no vector unit to fill.
     """
-    n_rows, row_words = matrix_shape
+    n_rows = matrix_shape[0]
     if probes_per_run is None:
         probes_per_run = 32 if jax.default_backend() == "cpu" else 128
     if rows_per_block is None:
-        if bit_probe:
-            target = max(cfg.L // 32, 1)
-        else:
-            # 2^21 / (W*128) rows: the bound from when the kernel unpacked
-            # a tile into a 2 MB f32 bit image, kept so plans and locality
-            # counters stay comparable (whether the tile should follow L
-            # instead is open: PERF.md)
-            target = max(8, min(cfg.L, (1 << 21) // max(row_words * 128, 1)))
-        rows_per_block = _pow2_block(n_rows, target)
+        rows_per_block = _pow2_block(n_rows, lanes.LANES)
     if n_rows % rows_per_block:
         raise ValueError(
             f"rows_per_block={rows_per_block} must divide n_rows={n_rows}"
@@ -531,9 +526,8 @@ def _execute_planned(matrix: jax.Array, reads: jax.Array, *,
         rows, rows_per_block=plan.rows_per_block,
         probes_per_run=plan.probes_per_run)
     got = probe_ops._planned_gather(
-        matrix, runs.block_ids, runs.group_ids, runs.offsets, runs.n_runs,
-        rows_per_block=plan.rows_per_block,
-        probes_per_run=plan.probes_per_run, n_probes=rows.shape[0],
+        matrix, runs.rows, runs.starts,
+        rows_per_block=plan.rows_per_block, n_probes=rows.shape[0],
         row_words=plan.row_words, interpret=interpret, use_ref=use_ref)
     got = got.reshape(locs.shape + (plan.row_words,))
     return (_finish_probe(got, locs, bit_probe=plan.bit_probe),

@@ -36,6 +36,7 @@ class ProbePlan:
                              # the (R, C) offset matrix)
     probe_index: np.ndarray  # (R_pad, C) int32 position in flattened (η·n)
                              # stream, -1 where offsets is
+    starts: np.ndarray       # (η·n,) bool: the probe starts a run
     n_runs: int              # true run count (before padding)
     n_probes: int
     eta: int
@@ -102,6 +103,7 @@ def plan_probe_runs(
         offsets=offs,
         run_lengths=np.bincount(run, minlength=n_runs).astype(np.int32),
         probe_index=pidx,
+        starts=start,
         n_runs=n_runs,
         n_probes=p * n,
         eta=p,
@@ -137,40 +139,40 @@ def probe_membership(
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "rows_per_block", "probes_per_run", "n_probes", "row_words",
-    "interpret", "use_ref",
+    "rows_per_block", "n_probes", "row_words", "interpret", "use_ref",
 ))
-def _planned_gather(matrix, bids, gids, offs, n_runs, *, rows_per_block,
-                    probes_per_run, n_probes, row_words, interpret, use_ref):
-    """Execute a row plan; return ``(n_probes, W)`` uint32 rows in probe
-    order. ``use_ref`` swaps the Pallas kernel for its pure-jnp oracle
-    (same plan, same result). The kernel takes and returns the transposed
-    view (see ``repro.kernels.lanes``)."""
+def _planned_gather(matrix, rows, starts, *, rows_per_block, n_probes,
+                    row_words, interpret, use_ref):
+    """Execute a group plan (``rows`` and ``starts``, ``(G, C)``); return
+    ``(n_probes, W)`` uint32 rows in probe order. ``use_ref`` swaps the
+    Pallas kernel for its pure-jnp oracle (same plan, same result). The
+    kernel takes and returns the transposed view (see
+    ``repro.kernels.lanes``)."""
     matrix = jnp.reshape(matrix, (-1, row_words))
-    n_groups = -(-n_probes // probes_per_run)
     if use_ref:
-        rows = ref.probe_rows_ref(
-            matrix, bids, gids, offs, rows_per_block=rows_per_block,
-            n_groups=n_groups)
+        got = ref.probe_rows_ref(matrix, rows, starts,
+                                 rows_per_block=rows_per_block,
+                                 n_probes=n_probes)
     else:
-        rows = kernel.probe_rows(
-            matrix.T, bids, gids, offs, n_runs,
-            rows_per_block=rows_per_block,
-            probes_per_run=probes_per_run, n_groups=n_groups,
-            interpret=interpret,
-        ).T
-    return rows[:n_probes]
+        got = kernel.probe_rows(matrix.T, rows, starts,
+                                rows_per_block=rows_per_block,
+                                n_probes=n_probes, interpret=interpret).T
+    return got[:n_probes]
 
 
 class DevicePlan(NamedTuple):
     """The run plan of one probe stream, as device arrays."""
 
-    block_ids: jax.Array   # (R,) int32 block per run
-    group_ids: jax.Array   # (R,) int32 output group per run
-    offsets: jax.Array     # (R, C) int32 offset in block, -1 off the run
-    n_runs: jax.Array      # () int32 true run count
-    run_hist: jax.Array    # (C + 1,) int32 runs of each length 0..C (the
-                           # pad runs are the length-0 ones)
+    rows: jax.Array        # (G, C) int32 row of each probe (0 past the end)
+    starts: jax.Array      # (G, C) int32 1 where a probe starts a run
+    n_runs: jax.Array      # () int32 run count (tile copies of the kernel)
+    run_hist: jax.Array    # (C + 1,) int32 runs of each length 0..C
+
+
+def _shift_left(x: jax.Array, k: int, fill: int) -> jax.Array:
+    """``x[:, j + k]``, and ``fill`` past the last column."""
+    return jnp.concatenate(
+        [x[:, k:], jnp.full((x.shape[0], k), fill, x.dtype)], axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("rows_per_block",
@@ -179,54 +181,42 @@ def device_plan_runs(rows: jax.Array, *, rows_per_block: int,
                      probes_per_run: int) -> DevicePlan:
     """Run-length-encode the ``(N,)`` probe stream ``rows`` on the device.
 
-    The plan :func:`plan_probe_runs` makes, run for run: probe ``p`` is
-    lane ``p % C`` of group ``p // C``, a run is a stretch of probes of
-    one block within one group, and pad runs repeat the last run's block
-    and group with offsets of -1. Without a host sync the run count
-    cannot size the arrays, so ``R`` is ``N`` rounded up to
-    ``lanes.RUNS_PER_BLOCK`` (a run holds at least one probe); the
-    kernel's grid stops at ``n_runs``, so the pad runs take no step.
-
-    No sort, no binary search and no gather of single elements (each
-    costs the TPU a pass of its own): runs are numbered by a cumsum of
-    start flags, so run ids are nondecreasing along the stream and within
-    each group, and a group's first lane starts a run. A run finds its
-    group by a cumsum over marks at the groups' first run ids (a scatter
-    of one element a group), and its lanes, offsets and block in that
-    group's row of the stream.
+    The runs :func:`plan_probe_runs` makes, in the kernel's group form:
+    probe ``p`` is lane ``p % C`` of group ``p // C``, and a run is a
+    stretch of probes of one block within one group, flagged at its first
+    probe. The lanes past the stream's end start nothing. No sort, no
+    scatter and no gather: a run's length is the distance to the next
+    start or to the stream's end, found by a cumulative min along its
+    group (shifts, not ``lax.cummin``, whose reduce-window takes the TPU
+    compiler nearly a minute at a screen batch's size).
     """
     n = rows.shape[0]
     c = probes_per_run
-    r = -(-n // lanes.RUNS_PER_BLOCK) * lanes.RUNS_PER_BLOCK
     g = -(-n // c)
+    tail = g * c - n
     rows = rows.astype(jnp.int32)
     blocks = rows // rows_per_block
     idx = jnp.arange(n, dtype=jnp.int32)
     start = (idx % c == 0) | (blocks != jnp.roll(blocks, 1))
-    run = jnp.cumsum(start.astype(jnp.int32), dtype=jnp.int32) - 1
-    # (G, C) views of the stream; lanes past its end carry the run id R,
-    # which no run has
-    tail = g * c - n
-    run2 = jnp.pad(run, (0, tail), constant_values=r).reshape(g, c)
-    row2 = jnp.pad(rows, (0, tail)).reshape(g, c)
-
-    rid = jnp.arange(r, dtype=jnp.int32)
-    group_first = jnp.zeros((r,), jnp.int32).at[run2[:, 0]].set(
-        1, indices_are_sorted=True, unique_indices=True)
-    gid = jnp.cumsum(group_first, dtype=jnp.int32) - 1  # pad runs: G - 1
-    mine = run2[gid] == rid[:, None]                    # (R, C)
-    lane_row = row2[gid]
-    lane_block = lane_row // rows_per_block
-    offsets = jnp.where(mine, lane_row - lane_block * rows_per_block, -1)
-    length = jnp.sum(mine, axis=1, dtype=jnp.int32)
-    block_ids = jnp.where(
-        length > 0, jnp.max(jnp.where(mine, lane_block, -1), axis=1),
-        blocks[-1])
-    run_hist = jnp.sum(
-        length[:, None] == jnp.arange(c + 1, dtype=jnp.int32), axis=0,
-        dtype=jnp.int32)
-    return DevicePlan(block_ids=block_ids, group_ids=gid,
-                      offsets=offsets, n_runs=run[-1] + 1, run_hist=run_hist)
+    start2 = jnp.pad(start, (0, tail)).reshape(g, c)
+    real = (jnp.arange(g * c, dtype=jnp.int32) < n).reshape(g, c)
+    lane = jnp.arange(c, dtype=jnp.int32)
+    # the first start or end at or after each lane: a cumulative min from
+    # the right, in log2(C) shifts
+    bound = jnp.where(start2 | ~real, lane, c)
+    shift = 1
+    while shift < c:
+        bound = jnp.minimum(bound, _shift_left(bound, shift, c))
+        shift *= 2
+    length = jnp.where(start2, _shift_left(bound, 1, c) - lane, 0)
+    length = length.reshape(-1, 1)
+    run_hist = jnp.sum(length == jnp.arange(1, c + 1, dtype=jnp.int32),
+                       axis=0, dtype=jnp.int32)
+    return DevicePlan(
+        rows=jnp.pad(rows, (0, tail)).reshape(g, c),
+        starts=start2.astype(jnp.int32),
+        n_runs=jnp.sum(start, dtype=jnp.int32),
+        run_hist=jnp.concatenate([jnp.zeros((1,), jnp.int32), run_hist]))
 
 
 def scatter_and_reduce(bits: jax.Array, plan: ProbePlan) -> jax.Array:

@@ -28,22 +28,23 @@ def probe_runs_ref(
 
 def probe_rows_ref(
     matrix: jax.Array,
-    block_ids: jax.Array,
-    group_ids: jax.Array,
-    offsets: jax.Array,
+    rows: jax.Array,
+    starts: jax.Array,
     *,
     rows_per_block: int,
-    n_groups: int,
+    n_probes: int,
 ) -> jax.Array:
-    """(n_groups·C, W) uint32 rows in probe order (untransposed, unlike
-    the kernel); the padding lanes of the last group read row 0."""
-    c = offsets.shape[1]
-    n_out = n_groups * c
-    lane = group_ids[:, None] * c + jnp.arange(c, dtype=jnp.int32)
-    rows = block_ids[:, None] * rows_per_block + offsets
-    at = jnp.zeros((n_out,), dtype=jnp.int32).at[
-        jnp.where(offsets >= 0, lane, n_out)].set(rows, mode="drop")
-    return matrix[at]
+    """(G·C, W) uint32 rows in probe order (untransposed, unlike the
+    kernel). Each probe reads its offset in the tile of its run, the
+    block of the run's first probe (the last start at or before it in its
+    group); the pad lanes past ``n_probes`` read 0."""
+    g, c = rows.shape
+    lane = jnp.arange(c, dtype=jnp.int32)
+    first = jax.lax.cummax(jnp.where(starts != 0, lane, 0), axis=1)
+    block = jnp.take_along_axis(rows // rows_per_block, first, axis=1)
+    at = (block * rows_per_block + rows % rows_per_block).reshape(-1)
+    real = jnp.arange(g * c) < n_probes
+    return jnp.where(real[:, None], matrix[jnp.where(real, at, 0)], 0)
 
 
 def query_membership_ref(bf_words: jax.Array, locs: jax.Array) -> jax.Array:

@@ -1,14 +1,14 @@
 """In-kernel helpers shared by the run-coalesced probe and insert kernels.
 
-Both kernels walk one grid step per run and read that run's ``C`` lane
-values from an ``(R, C)`` int32 array. Mosaic only tiles blocks whose last
-two dimensions are multiples of (8, 128) or the whole array, so a run's
-row is fetched as part of an ``(8, C)`` block (one DMA per eight runs) and
-picked out here. The row gathers and bit images are one-hot matmuls on the
-MXU; words are split into four byte planes, so every operand and every
-partial sum is an integer below 256, which bf16 and f32 hold exactly.
-All arithmetic is int32: Mosaic refuses unsigned-to-float casts and
-unsigned reductions, so uint32 words are bitcast at the kernel boundary.
+The insert kernel walks one grid step per run and reads that run's ``C``
+lane values from an ``(R, C)`` int32 array. Mosaic only tiles blocks whose
+last two dimensions are multiples of (8, 128) or the whole array, so a
+run's row is fetched as part of an ``(8, C)`` block (one DMA per eight
+runs) and picked out here. Its bit images are one-hot matmuls on the MXU;
+words are split into four byte planes, so every operand and every partial
+sum is an integer below 256, which bf16 and f32 hold exactly. All
+arithmetic is int32: Mosaic refuses unsigned-to-float casts and unsigned
+reductions, so uint32 words are bitcast at the kernel boundary.
 
 Both kernels see the packed ``(n_rows, W)`` matrix transposed, as
 ``(W, n_rows)``. For ``W`` that is not a multiple of 128 that is how XLA
@@ -24,9 +24,10 @@ import jax
 import jax.numpy as jnp
 
 RUNS_PER_BLOCK = 8    # sublane tile: one (8, C) lane block serves 8 runs
+LANES = 128           # lane tile: the narrowest (W, rows) tile a DMA copies
 # Scalar-prefetch operands live in SMEM, 1 MiB on a v5e core: one kernel
-# call takes at most this many runs (two int32 ids per run), and longer
-# plans are cut into calls that pass their output on.
+# call takes at most this many runs or probes (two int32 scalars each),
+# and longer plans are cut into calls that pass their output on.
 MAX_RUNS_PER_CALL = 1 << 16
 
 

@@ -19,7 +19,7 @@ from repro import obs
 from repro.core import idl
 from repro.index import query
 from repro.index.engines import BitSlicedIndex
-from repro.kernels.idl_probe import ops as probe_ops
+from repro.kernels.idl_probe import kernel, ops as probe_ops
 from repro.obs import metrics as obs_metrics
 
 CFG = idl.IDLConfig(k=31, t=16, L=1 << 10, eta=2, m=1 << 16)
@@ -59,34 +59,33 @@ def test_device_plan_equals_numpy_plan(stream, probes_per_run):
     got = jax.device_get(probe_ops.device_plan_runs(
         jnp.asarray(rows, dtype=jnp.int32), rows_per_block=RPB,
         probes_per_run=probes_per_run))
-    n = want.n_runs
+    n, size = want.n_runs, rows.size
     assert int(got.n_runs) == n
-    np.testing.assert_array_equal(got.block_ids[:n], want.block_ids[:n])
-    np.testing.assert_array_equal(got.group_ids[:n], want.group_ids[:n])
-    np.testing.assert_array_equal(got.offsets[:n], want.offsets[:n])
-    # R = the probe count rounded up to the kernel's 8-run blocks; pad
-    # runs repeat the last run's block and group and probe nothing
-    r = got.block_ids.shape[0]
-    assert r == -(-rows.size // 8) * 8 and got.offsets.shape == (
-        r, probes_per_run)
-    assert (got.block_ids[n:] == want.block_ids[n - 1]).all()
-    assert (got.group_ids[n:] == want.group_ids[n - 1]).all()
-    assert (got.offsets[n:] == -1).all()
+    # (G, C): the stream in groups of C; the lanes past its end are 0 and
+    # start nothing
+    g = -(-size // probes_per_run)
+    assert got.rows.shape == got.starts.shape == (g, probes_per_run)
+    np.testing.assert_array_equal(got.rows.reshape(-1)[:size], rows)
+    np.testing.assert_array_equal(got.starts.reshape(-1)[:size],
+                                  want.starts)
+    assert not got.rows.reshape(-1)[size:].any()
+    assert not got.starts.reshape(-1)[size:].any()
     hist = np.bincount(want.run_lengths, minlength=probes_per_run + 1)
     np.testing.assert_array_equal(got.run_hist[1:], hist[1:])
-    assert got.run_hist[0] == r - n
+    assert got.run_hist[0] == 0
     if stream == "one_block":
-        assert n == -(-rows.size // probes_per_run)   # one run a group
+        assert n == -(-size // probes_per_run)        # one run a group
     if stream == "every_probe_a_run":
-        assert n == rows.size
+        assert n == size
 
 
 @pytest.mark.parametrize("stream", ["idl_bits", "one_block", "rh_rows"])
 def test_kernel_takes_no_step_past_the_run_count(stream):
     """The interpreted kernel, fed the device plan, gathers every probe's
-    row; the pad runs are poisoned (offset 0 on every lane, which would
-    overwrite the last group's lanes) and change nothing, because the
-    grid stops at ``n_runs``. The oracle, which runs every run, sees the
+    row; the pad lanes of the last group are poisoned (each starts a run
+    of another block) and change nothing, because the kernel stops at
+    the stream's end: it copies no tile for them and reads none into
+    them. The same kernel told that the pad lanes are probes reads the
     poison."""
     rows = np.asarray(STREAMS[stream](), dtype=np.int64)
     n_rows = -(-(int(rows.max()) + 1) // RPB) * RPB
@@ -96,20 +95,23 @@ def test_kernel_takes_no_step_past_the_run_count(stream):
     runs = probe_ops.device_plan_runs(
         jnp.asarray(rows, dtype=jnp.int32), rows_per_block=RPB,
         probes_per_run=c)
-    pad = jnp.arange(runs.offsets.shape[0])[:, None] >= runs.n_runs
-    poisoned = jnp.where(pad, 0, runs.offsets)
+    pad = (jnp.arange(runs.rows.size) >= rows.size).reshape(runs.rows.shape)
+    poison = int((rows[-1] // RPB + 1) % (n_rows // RPB) * RPB + 1)
+    poisoned = (jnp.where(pad, poison, runs.rows),
+                jnp.where(pad, 1, runs.starts))
     assert int(jnp.sum(pad)) > 0
 
-    def gather(**kw):
-        return np.asarray(probe_ops._planned_gather(
-            matrix, runs.block_ids, runs.group_ids, poisoned, runs.n_runs,
-            rows_per_block=RPB, probes_per_run=c, n_probes=rows.size,
-            row_words=w, **kw))
+    def gather(n_probes):
+        return np.asarray(kernel.probe_rows(
+            matrix.T, *poisoned, rows_per_block=RPB, n_probes=n_probes,
+            interpret=True)).T
 
     want = np.asarray(matrix)[rows]
-    np.testing.assert_array_equal(gather(interpret=True, use_ref=False),
-                                  want)
-    assert not np.array_equal(gather(interpret=False, use_ref=True), want)
+    got = gather(rows.size)
+    np.testing.assert_array_equal(got[:rows.size], want)
+    assert not got[rows.size:].any()
+    assert (gather(runs.rows.size)[rows.size:]
+            == np.asarray(matrix)[poison]).all()
 
 
 @jax.jit
@@ -210,4 +212,38 @@ def test_locality_queue_loses_no_batch_under_threads():
     assert counts["batches"] == total
     assert counts["probes"] == total * rplan.n_probes
     assert counts["probe_runs"] == total * rplan.n_runs
+    obs.reset()
+
+
+@pytest.mark.parametrize("scheme", ["idl", "rh"])
+def test_tile_bytes_per_probe_counts_the_kernels_copies(scheme):
+    """The locality counters of a served batch count the tiles the kernel
+    copies: ``planned_tile_bytes / probes`` is the numpy plan's
+    ``n_runs × 128 × W × 4 / probes`` at the served 128-row block."""
+    reads = jnp.asarray(np.random.default_rng(8).integers(
+        0, 4, size=(3, 140), dtype=np.uint8))
+    eng = BitSlicedIndex.build(CFG, scheme, n_files=96).insert_batch(
+        reads[:2], np.asarray([4, 70]))
+    plan = query.plan_query(CFG, scheme, reads.shape, eng.words.shape,
+                            bit_probe=False, lane32=True)
+    assert plan.rows_per_block == 128
+    w = eng.words.shape[1]
+    query.drain_locality()
+    obs.reset()
+    got = plan.execute(eng.words, reads, backend="idl_probe")
+    np.testing.assert_array_equal(
+        np.asarray(got),
+        np.asarray(plan.execute(eng.words, reads, backend="jnp")))
+    assert query.drain_locality() == 1
+    locs = np.asarray(plan.locations(reads)).reshape(-1)
+    want = probe_ops.plan_probe_runs(locs, block_bits=128,
+                                     probes_per_run=plan.probes_per_run)
+    snap = obs_metrics.DEFAULT.snapshot()
+    counts = {name: obs_metrics.counter_total(
+        snap, f"locality.{name}", {"scheme": scheme, "op": "query"})
+        for name in ("planned_tile_bytes", "probes")}
+    assert counts["probes"] == want.n_probes == locs.size
+    assert counts["planned_tile_bytes"] == want.n_runs * 128 * w * 4
+    assert (counts["planned_tile_bytes"] / counts["probes"]
+            == want.n_runs * 128 * w * 4 / want.n_probes)
     obs.reset()

@@ -7,6 +7,7 @@ import pytest
 
 from repro.core import bloom, idl, minhash
 from repro.kernels.idl_insert import ops as ins_ops
+from repro.kernels.idl_probe import kernel as kernel_mod
 from repro.kernels.idl_probe import ops as probe_ops, ref as probe_ref
 from repro.kernels.window_min import kernel as wm_kernel
 
@@ -31,6 +32,27 @@ class TestWindowMinKernel:
         got = wm_kernel.window_min(a, w=9, tile=128, interpret=True)
         want = minhash.sliding_window_min(a, 9)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want))
+
+
+def _row_stream(rng, kind: str, n_rows: int, rpb: int) -> np.ndarray:
+    """Row probes of one kind, none a multiple of 128 or 32 long."""
+    if kind == "mixed":
+        rows = rng.integers(0, n_rows, size=(3, 97))
+        rows[1].sort()  # one stream with long block runs, two scattered
+        return rows
+    idx = np.arange(300)
+    if kind == "one_long_run":
+        return (5 * rpb + idx % rpb) % n_rows
+    assert kind == "every_probe_a_run"
+    return (idx % 2 * rpb + idx % rpb) % n_rows
+
+
+def _group_form(rows: np.ndarray, starts: np.ndarray, c: int):
+    """``(G, C)`` rows and run flags of a flat stream, as the kernel takes
+    them: lanes past the stream are 0."""
+    pad = -rows.size % c
+    return (jnp.asarray(np.pad(rows, (0, pad)).reshape(-1, c), jnp.int32),
+            jnp.asarray(np.pad(starts, (0, pad)).reshape(-1, c), jnp.int32))
 
 
 def _build_bf(rng, cfg, n=1500):
@@ -68,32 +90,67 @@ class TestProbeKernel:
         direct = bloom.query_packed(words, jnp.asarray(locs.astype(np.uint32)))
         np.testing.assert_array_equal(np.asarray(got), np.asarray(direct))
 
-    @pytest.mark.parametrize("n_rows,w,rpb,C", [
-        (256, 3, 16, 32),      # odd word count (COBS group slice)
-        (1 << 12, 1, 64, 128), # flat packed BF as a (m/32, 1) matrix
-        (512, 8, 8, 64),       # RAMBO-transpose-like wide rows
+    @pytest.mark.parametrize("n_rows,w,rpb,C,stream", [
+        (256, 3, 16, 32, "mixed"),      # odd word count (COBS group slice)
+        (1 << 12, 1, 64, 128, "mixed"), # flat packed BF as a (m/32, 1) matrix
+        (512, 8, 8, 64, "mixed"),       # RAMBO-transpose-like wide rows
+        # the served geometry: one 128-row lane tile a block
+        (1 << 12, 1, 128, 128, "mixed"),
+        (1 << 12, 3, 128, 128, "mixed"),
+        (1 << 14, 32, 128, 128, "mixed"),
+        (1 << 12, 32, 128, 128, "one_long_run"),
+        (1 << 12, 32, 128, 128, "every_probe_a_run"),
+        (1 << 12, 32, 128, 32, "every_probe_a_run"),
     ])
-    def test_probe_rows_sweep_vs_ref(self, rng, n_rows, w, rpb, C):
+    def test_probe_rows_sweep_vs_ref(self, rng, n_rows, w, rpb, C, stream):
         """The generalized (rows, W) row-gather kernel: Pallas == ref ==
-        direct numpy indexing, in probe order, for arbitrary matrices."""
+        direct numpy indexing, in probe order, for arbitrary matrices.
+        Every stream is ragged (its last group part pad); ``mixed`` has one
+        part with long block runs and two scattered, ``one_long_run`` fills
+        whole groups from one block, and ``every_probe_a_run`` makes more
+        tile copies than the kernel's ring has slots."""
         matrix = jnp.asarray(
             rng.integers(0, 2 ** 32, size=(n_rows, w), dtype=np.uint32))
-        rows = rng.integers(0, n_rows, size=(3, 97))
-        rows[1].sort()  # one stream with long block runs, two scattered
+        rows = _row_stream(rng, stream, n_rows, rpb)
         plan = probe_ops.plan_probe_runs(rows, block_bits=rpb,
                                          probes_per_run=C)
+        group_rows, starts = _group_form(rows.reshape(-1), plan.starts, C)
+
         def gather(**kw):
             return probe_ops._planned_gather(
-                matrix, jnp.asarray(plan.block_ids),
-                jnp.asarray(plan.group_ids), jnp.asarray(plan.offsets),
-                jnp.int32(plan.n_runs), rows_per_block=rpb,
-                probes_per_run=C, n_probes=plan.n_probes, row_words=w, **kw)
+                matrix, group_rows, starts, rows_per_block=rpb,
+                n_probes=plan.n_probes, row_words=w, **kw)
 
         got = gather(interpret=True, use_ref=False)
         got_ref = gather(interpret=False, use_ref=True)
         want = np.asarray(matrix)[rows.reshape(-1)]
         np.testing.assert_array_equal(np.asarray(got), want)
         np.testing.assert_array_equal(np.asarray(got_ref), want)
+
+    @pytest.mark.parametrize("copies_in_flight", [1, 3, 15])
+    def test_probe_rows_follows_the_run_flags(self, rng, copies_in_flight):
+        """Each probe reads its run's tile, the block of the run's first
+        probe, however few copies the ring holds: with extra starts in
+        runs and starts left out at block changes, the kernel still equals
+        the oracle, and past ``n_probes`` it reads nothing (0)."""
+        n_rows, w, rpb, c = 1 << 11, 3, 128, 32
+        matrix = jnp.asarray(
+            rng.integers(0, 2 ** 32, size=(n_rows, w), dtype=np.uint32))
+        rows = _row_stream(rng, "mixed", n_rows, rpb).reshape(-1)
+        flags = rng.random(rows.size) < 0.3
+        flags[::c] = True
+        group_rows, starts = _group_form(rows, flags, c)
+        got = kernel_mod.probe_rows(
+            matrix.T, group_rows, starts, rows_per_block=rpb,
+            n_probes=rows.size, copies_in_flight=copies_in_flight,
+            interpret=True)
+        want = probe_ref.probe_rows_ref(matrix, group_rows, starts,
+                                        rows_per_block=rpb,
+                                        n_probes=rows.size)
+        np.testing.assert_array_equal(np.asarray(got).T, np.asarray(want))
+        assert not np.asarray(want)[rows.size:].any()
+        assert not np.array_equal(np.asarray(want)[:rows.size],
+                                  np.asarray(matrix)[rows])
 
     def test_dma_savings_idl_vs_rh(self, rng):
         """The kernel's DMA count IS the paper's cache-miss metric on TPU:
