@@ -35,19 +35,7 @@ router answers bit-identically to a direct single-service run across
 2 engines × {idl, rh} schemes, with compile counts per (bucket, backend)
 == 1 per replica, plus a live hot-swap with zero dropped futures.
 
-``--procs N`` switches to the **process fabric** benchmark: the same
-stream served by :class:`ProcessFabric` fleets of 1..N mmap-booted worker
-processes behind one gateway, recorded as a per-worker-count scaling
-curve against the in-process router baseline, with gateway-vs-in-process
-parity and a zero-drop rolling restart asserted in-bench. A
-``kmer_cache`` section re-serves a deep-coverage overlapping stream with
-per-worker membership caches off vs on (parity asserted for both fleets,
-gateway-merged hit rate > 0 asserted). Writes ``BENCH_fabric.json`` (in
-``--smoke`` too — CI uploads it; the smoke record is marked
-``"smoke": true``).
-
     PYTHONPATH=src python -m benchmarks.cluster_bench [--smoke]
-    PYTHONPATH=src python -m benchmarks.cluster_bench --procs 2 [--smoke]
 
 Writes ``BENCH_cluster.json`` (full mode) next to the repo root.
 """
@@ -55,10 +43,8 @@ Writes ``BENCH_cluster.json`` (full mode) next to the repo root.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import pathlib
-import shutil
 import tempfile
 import time
 
@@ -66,16 +52,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import bench_metadata, overlapping_stream, timeit
+from benchmarks.common import bench_metadata, timeit
 from repro.core import idl
 from repro.data import genome
 from repro.index import BitSlicedIndex, CobsIndex, ingest, store
 from repro.serving import (
     AsyncScheduler,
-    FabricConfig,
     GeneSearchService,
-    KmerCacheConfig,
-    ProcessFabric,
     ReplicaRouter,
     RouterConfig,
     SchedulerConfig,
@@ -321,214 +304,13 @@ def _assert_hot_swap(m: int, tmp: pathlib.Path) -> None:
           "post-swap results on the new version")
 
 
-# ---------------------------------------------------------------------------
-# Process fabric: per-worker-count scaling curve + parity + zero-drop swap.
-# ---------------------------------------------------------------------------
-
-def _fabric_closed_loop(fab: ProcessFabric, stream) -> None:
-    futures = [fab.submit(q) for q in stream]
-    for f in futures:
-        f.result(timeout=300)
-
-
-def _fabric_paced(fab: ProcessFabric, stream, gaps) -> np.ndarray:
-    """CO-safe paced replay through the gateway (see _Tier.serve_paced)."""
-    lat = np.zeros(len(stream))
-    sched_t = time.perf_counter()
-    futures = []
-    for i, (q, gap) in enumerate(zip(stream, gaps)):
-        sched_t += gap
-        now = time.perf_counter()
-        if now < sched_t:
-            time.sleep(sched_t - now)
-        fut = fab.submit(q)
-        fut.add_done_callback(
-            lambda f, i=i, s=sched_t: lat.__setitem__(
-                i, (time.perf_counter() - s) * 1e3))
-        futures.append(fut)
-    fab.drain()
-    for f in futures:
-        f.result(timeout=300)
-    return lat
-
-
-def _assert_fabric_swap(fab: ProcessFabric, stream, ref) -> dict:
-    """Rolling restart under traffic: zero dropped futures, version+1."""
-    in_flight = [fab.submit(q) for q in stream]
-    old_version = fab.version
-    new_version = fab.rolling_restart()           # same snapshot, v+1
-    after = [fab.submit(q) for q in stream[:8]]
-    results = [f.result(timeout=300) for f in in_flight + after]
-    for got, want in zip(results, list(ref) + list(ref[:8])):
-        np.testing.assert_array_equal(np.asarray(got.matches),
-                                      np.asarray(want.matches))
-    assert new_version == old_version + 1
-    assert all(r.version == new_version for r in
-               results[len(in_flight):])
-    return {"in_flight": len(in_flight) + len(after),
-            "dropped": 0, "new_version": new_version}
-
-
-def run_fabric(max_procs: int, m: int, n_files: int, n_requests: int,
-               iters: int, rps: float, backend: str,
-               smoke: bool) -> dict:
-    eng = _build_index(m, n_files, genome_len=3_000)
-    archive = genome.synth_archive(n_files=n_files, genome_len=3_000,
-                                   seed=42)
-    pool = [f.reads(230, 4)[i % 4] for i, f in enumerate(archive)]
-    stream, gaps = _poisson_stream(pool, n_requests, rps, seed=7)
-    svc_cfg = ServiceConfig(backend=backend, max_batch=16)
-    sched_cfg = SchedulerConfig(max_delay_ms=2.0)
-
-    # in-process references: bit-exact answers + the single-interpreter
-    # router the fabric has to beat once it has cores to scale across
-    ref = GeneSearchService(eng, svc_cfg).search(stream)
-    router_tier = _Tier("router", eng, backend, n_replicas=2)
-    try:
-        router_s = timeit(lambda: router_tier.serve_closed_loop(stream),
-                          repeats=iters, warmup=1)
-    finally:
-        router_tier.close()
-
-    tmp = tempfile.mkdtemp(prefix="fabric_bench_")
-    curve: dict = {}
-    swap: dict = {}
-    try:
-        snap = store.save(eng, str(pathlib.Path(tmp) / "snap"))
-        for n in range(1, max_procs + 1):
-            fab = ProcessFabric(snap, FabricConfig(
-                n_workers=n, service=svc_cfg, scheduler=sched_cfg))
-            try:
-                # warmup pass: each worker compiles its buckets, and the
-                # answers double as the gateway-vs-in-process parity check
-                futures = [fab.submit(q) for q in stream]
-                for got, want in zip(
-                        [f.result(timeout=300) for f in futures], ref):
-                    np.testing.assert_array_equal(
-                        np.asarray(got.matches), np.asarray(want.matches))
-                stream_s = timeit(
-                    lambda: _fabric_closed_loop(fab, stream),
-                    repeats=iters, warmup=1)
-                lat = _fabric_paced(fab, stream, gaps)
-                curve[str(n)] = {
-                    "throughput_rps": round(n_requests / stream_s, 1),
-                    "latency_ms": {
-                        "p50": round(float(np.percentile(lat, 50)), 3),
-                        "p99": round(float(np.percentile(lat, 99)), 3),
-                    },
-                }
-                if n == max_procs:
-                    swap = _assert_fabric_swap(fab, stream, ref)
-            finally:
-                fab.close()
-
-        # per-worker membership caches over a deep-coverage overlapping
-        # stream: cache-off vs cache-on fleets at max_procs, parity vs
-        # the in-process reference asserted on both, gateway-merged hit
-        # rate recorded (smoke included — CI gates on these asserts)
-        overlap = overlapping_stream(pool, n_requests, seed=11,
-                                     read_len=230, region_len=600)
-        ref_overlap = GeneSearchService(eng, svc_cfg).search(overlap)
-        cache_rec: dict = {}
-        for label, cfg in (
-                ("cache_off", svc_cfg),
-                ("cache_on", dataclasses.replace(
-                    svc_cfg, kmer_cache=KmerCacheConfig(capacity=1 << 17)))):
-            fab = ProcessFabric(snap, FabricConfig(
-                n_workers=max_procs, service=cfg, scheduler=sched_cfg))
-            try:
-                # warmup pass doubles as the parity gate
-                futures = [fab.submit(q) for q in overlap]
-                for got, want in zip(
-                        [f.result(timeout=300) for f in futures],
-                        ref_overlap):
-                    np.testing.assert_array_equal(
-                        np.asarray(got.matches), np.asarray(want.matches))
-                secs = timeit(lambda: _fabric_closed_loop(fab, overlap),
-                              repeats=iters, warmup=1)
-                cache_rec[label + "_rps"] = round(n_requests / secs, 1)
-                if label == "cache_on":
-                    cs = fab.cache_stats()
-                    assert cs is not None and cs["hits"] > 0, cs
-                    cache_rec["hit_rate"] = round(cs["hit_rate"], 4)
-                    cache_rec["cache"] = cs
-                else:
-                    assert fab.cache_stats() is None
-            finally:
-                fab.close()
-        cache_rec["speedup"] = round(
-            cache_rec["cache_on_rps"] / cache_rec["cache_off_rps"], 2)
-        cache_rec["note"] = (
-            "overlapping read_len=230 windows into 4 concatenated "
-            "600bp regions at "
-            f"{max_procs} workers; per-worker caches ride the pickled "
-            "ServiceConfig; parity vs the in-process service asserted "
-            "for BOTH fleets; hit_rate is the gateway-merged lifetime "
-            "counter, cold misses included")
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-
-    rps_1 = curve["1"]["throughput_rps"]
-    return {
-        "host": bench_metadata(),
-        "smoke": smoke,
-        "config": {
-            "engine": "bitsliced", "scheme": "idl", "m": m,
-            "n_files": n_files, "n_requests": n_requests,
-            "backend": backend, "max_batch": 16, "offered_rps": rps,
-            "device": jax.default_backend(),
-        },
-        "in_process_router_rps": round(n_requests / router_s, 1),
-        "procs": curve,
-        "scaling_vs_1proc": {
-            n: round(c["throughput_rps"] / rps_1, 2)
-            for n, c in curve.items()},
-        "rolling_swap": swap,
-        "kmer_cache": cache_rec,
-        "parity": ("gateway == in-process service, bit-identical, at "
-                   "every worker count (asserted in-bench)"),
-        "notes": [
-            "workers are separate interpreters mmap-ing one snapshot: "
-            "no GIL or XLA:CPU device shared between them — the fabric "
-            "scales with cores, which host.cpu_count records",
-            "on a 1-core host the curve is flat-to-negative (worker "
-            "processes time-slice one core and pay IPC on top); the "
-            "in-process router is the right tier there — read the curve "
-            "against host.cpu_count, never bare",
-            "rolling_swap: futures submitted before and during the "
-            "restart all resolved bit-identically (zero dropped), and "
-            "post-swap results carry the new fleet version",
-        ],
-    }
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
-                    help="small config; assert parity + swap; no JSON "
-                         "(except --procs mode, which always writes "
-                         "BENCH_fabric.json)")
-    ap.add_argument("--procs", type=int, default=0, metavar="N",
-                    help="benchmark the process fabric at 1..N worker "
-                         "processes; writes BENCH_fabric.json")
+                    help="small config; assert parity + swap; no JSON")
     args = ap.parse_args()
 
     root = pathlib.Path(__file__).resolve().parent.parent
-
-    if args.procs:
-        if args.smoke:
-            res = run_fabric(max_procs=args.procs, m=1 << 18, n_files=16,
-                             n_requests=32, iters=2, rps=2000,
-                             backend="jnp", smoke=True)
-        else:
-            res = run_fabric(max_procs=args.procs, m=1 << 22, n_files=64,
-                             n_requests=128, iters=3, rps=2000,
-                             backend="jnp", smoke=False)
-        out_path = root / "BENCH_fabric.json"
-        out_path.write_text(json.dumps(res, indent=2) + "\n")
-        print(json.dumps(res, indent=2))
-        print(f"\nwrote {out_path}")
-        return
 
     if args.smoke:
         _assert_parity(m=1 << 16)

@@ -29,8 +29,8 @@ def bench_metadata() -> dict:
     """Host/build provenance stamped into every ``BENCH_*.json``.
 
     Scaling numbers are meaningless without the hardware they ran on —
-    a 2-worker fabric on a 1-core CI box CANNOT beat one interpreter,
-    and the record has to say so. First slice of the cross-arch harness
+    a 2-shard fleet on a 1-core CI box CANNOT beat one shard, and the
+    record has to say so. First slice of the cross-arch harness
     (ROADMAP: same benches, many boxes, keyed by this metadata).
     """
     return {

@@ -9,8 +9,8 @@ subtrees excluded).
 
 Scaling numbers are meaningless across different hardware, so records
 whose host keys disagree are still compared but loudly flagged: the join
-column says which fields differ (a 2-worker fabric on a 1-core CI box
-cannot beat one interpreter, and the table has to say so).
+column says which fields differ (a 2-shard fleet on a 1-core CI box
+cannot beat one shard, and the table has to say so).
 
 ``--fail-on-regression PCT`` makes the exit code a CI gate: metrics whose
 names classify as higher-is-better (throughput, speedup, hit_rate, ...)

@@ -10,16 +10,6 @@ claims ledger rows:
   an in-process :class:`ScatterGatherRouter`; every answer is asserted
   bit-identical to one :class:`GeneSearchService` over the joined
   (unsharded) index BEFORE anything is recorded. 16 combos, all exact.
-* **proc parity** (smoke + full): the same check through REAL shard
-  worker processes (2 mmap-booted shards behind one gateway), one
-  engine per partition axis.
-* **shard kill** (smoke + full): kill -9 one shard process mid-stream.
-  Row-probe (bit-sliced): every future resolves, late answers name the
-  dead shard's files in ``missing_files`` and stay exact on the
-  surviving files. Bit-probe (rambo): affected futures raise
-  ``ShardDeadError`` — loud, never a silently-inflated answer. The
-  recorded ``shard_kill.dropped`` counts futures that neither resolved
-  nor raised: it must be 0.
 * **build scaling** (full only): wall-clock of the parallel sharded
   build vs the serial ``build_archive`` over the same files. Read the
   curve with ``host.cpu_count`` in hand — shard builds contend for one
@@ -61,7 +51,6 @@ from repro.serving import (
     ScatterConfig,
     ScatterGatherRouter,
     ServiceConfig,
-    ShardDeadError,
 )
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
@@ -140,91 +129,6 @@ def parity_sweep(files, queries, tmp: str) -> dict:
             "thetas": list(THETAS), "n_shards": 2}
 
 
-def proc_parity(files, queries, tmp: str) -> dict:
-    """The same answers through real shard worker processes."""
-    out = {}
-    for engine, theta in (("bitsliced", 1.0), ("rambo", 0.6)):
-        set_dir = f"{tmp}/{engine}-idl"        # reuse the sweep's set
-        _, states = shards.load_shard_set(set_dir)
-        sm = shards.read_set_meta(set_dir)
-        full = shards.join_states(sm.spec, states)
-        svc_cfg = ServiceConfig(theta=theta, max_batch=4)
-        want = GeneSearchService(full, svc_cfg).search(queries)
-        with ScatterGatherRouter(set_dir, ScatterConfig(
-                procs=True, service=svc_cfg)) as router:
-            got = router.search(queries)
-            _assert_results_equal(want, got, f"procs {engine}")
-        out[engine] = {"n_shards": sm.spec.n_shards, "axis": sm.spec.axis,
-                       "equal": True, "theta": theta}
-        print(f"  proc parity {engine} (axis={sm.spec.axis}) OK")
-    return out
-
-
-def shard_kill(files, queries, tmp: str) -> dict:
-    """kill -9 one shard process mid-stream on each partition axis and
-    account for EVERY submitted future: resolved exactly, resolved with
-    named missing files, or raised ShardDeadError. dropped must be 0."""
-    stream = [queries[i % len(queries)] for i in range(24)]
-    out = {"submitted": 0, "resolved": 0, "loud_errors": 0, "dropped": 0}
-
-    # row-probe axis: partial truth, honestly labeled
-    set_dir = f"{tmp}/bitsliced-idl"
-    sm = shards.read_set_meta(set_dir)
-    lost = shards.shard_files(sm.spec, 1)
-    kept = sorted(set(range(sm.spec.meta.n_files)) - set(lost))
-    _, states = shards.load_shard_set(set_dir)
-    oracle = GeneSearchService(
-        shards.join_states(sm.spec, states),
-        ServiceConfig(max_batch=4))
-    want = oracle.search(stream)
-    row = {"with_missing_files": 0}
-    with ScatterGatherRouter(set_dir, ScatterConfig(
-            procs=True, service=ServiceConfig(max_batch=4))) as router:
-        router.search(queries[:2])             # warm both shards
-        futures = [router.submit(q) for q in stream]
-        router.kill_shard(1)
-        for w, f in zip(want, futures):
-            res = f.result(timeout=120)        # raises if dropped
-            out["resolved"] += 1
-            if res.missing_files:
-                assert res.missing_files == lost
-                row["with_missing_files"] += 1
-            gm = np.asarray(res.matches)
-            assert np.array_equal(gm[kept], np.asarray(w.matches)[kept])
-        out["submitted"] += len(futures)
-    row["lost_files"] = len(lost)
-    print(f"  row-probe kill: {row['with_missing_files']}/"
-          f"{len(stream)} answers carried missing_files, 0 dropped")
-
-    # bit-probe axis: fail loud, never inflate the FPR
-    set_dir = f"{tmp}/rambo-idl"
-    bit = {"loud_errors": 0}
-    with ScatterGatherRouter(set_dir, ScatterConfig(
-            procs=True, service=ServiceConfig(max_batch=4))) as router:
-        router.search(queries[:2])
-        futures = [router.submit(q) for q in stream]
-        router.kill_shard(0)
-        for f in futures:
-            try:
-                f.result(timeout=120)
-                out["resolved"] += 1
-            except ShardDeadError:
-                out["loud_errors"] += 1
-                bit["loud_errors"] += 1
-        out["submitted"] += len(futures)
-    assert bit["loud_errors"] > 0, \
-        "kill landed after the whole stream resolved; nothing asserted"
-    print(f"  bit-probe kill: {bit['loud_errors']}/{len(stream)} "
-          f"futures failed loud, 0 dropped")
-
-    out["dropped"] = out["submitted"] - out["resolved"] \
-        - out["loud_errors"]
-    assert out["dropped"] == 0, out
-    out["row_probe"] = row
-    out["bit_probe"] = bit
-    return out
-
-
 def build_scaling(repeats: int) -> dict:
     """Parallel sharded build vs serial build_archive, same files."""
     n_files = 128
@@ -278,9 +182,8 @@ def scatter_throughput(files, queries, tmp: str, repeats: int) -> dict:
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
-                    help="CI mode: parity + proc parity + shard-kill "
-                         "asserts only (still rewrites BENCH_shards.json, "
-                         'marked "smoke": true)')
+                    help="CI mode: the parity sweep only (still rewrites "
+                         'BENCH_shards.json, marked "smoke": true)')
     ap.add_argument("--repeats", type=int, default=3)
     args = ap.parse_args()
 
@@ -289,10 +192,6 @@ def main() -> None:
     with tempfile.TemporaryDirectory(prefix="shards_bench_") as tmp:
         print("parity sweep (sharded build + scatter-gather vs oracle):")
         res["parity"] = parity_sweep(files, queries, tmp)
-        print("proc-mode parity (real shard worker processes):")
-        res["proc_parity"] = proc_parity(files, queries, tmp)
-        print("shard kill -9 mid-stream:")
-        res["shard_kill"] = shard_kill(files, queries, tmp)
         if not args.smoke:
             print("build scaling:")
             res["build"] = build_scaling(args.repeats)
@@ -300,9 +199,9 @@ def main() -> None:
             res["throughput"] = scatter_throughput(files, queries, tmp,
                                                    args.repeats)
     res["notes"] = [
-        "parity/proc_parity/shard_kill are exactness gates asserted "
-        "before this record is written — the numbers that matter are "
-        "the counts (combos, dropped), not wall-clock",
+        "parity is an exactness gate asserted before this record is "
+        "written — the number that matters is the count of combos, "
+        "not wall-clock",
         "build and throughput wall-clock run on host.cpu_count cores "
         "with ONE in-order XLA:CPU device: shard threads contend for "
         "it, so ~flat curves here are honest — the per-shard scaling "

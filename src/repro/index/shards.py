@@ -517,7 +517,7 @@ def is_shard_set(directory: str) -> bool:
 
 def read_set_meta(directory: str) -> ShardSetMeta:
     """Read + verify the top-level manifest — O(manifest), no array bytes.
-    The scatter gateway boots its geometry from this alone."""
+    The scatter router reads its geometry from this alone."""
     path = os.path.join(directory, SET_MANIFEST)
     if not os.path.exists(path):
         raise ShardSetError(

@@ -26,11 +26,10 @@ all four engines × schemes). Design points:
   one ``.npy`` header read, not a data pass — so a truncated file still
   fails at open time even with verification off.
 
-Cold-start cost is the reason the knobs exist: a process fleet booting K
-workers from ONE on-disk snapshot (:mod:`repro.serving.fabric`) wants
-each worker's open to be O(manifest), not O(index bytes). ``mmap=True``
-+ ``verify="lazy"`` reads the data exactly once (the page cache shares
-that single read across all K workers); ``device=False`` additionally
+Cold-start cost is the reason the knobs exist: a server booting from an
+on-disk snapshot wants the open to be O(manifest), not O(index bytes).
+``mmap=True`` + ``verify="lazy"`` reads the data exactly once, while the
+caller already serves; ``device=False`` additionally
 keeps the word matrices as memory-mapped numpy leaves, deferring even
 the page-in until first use (the first computation converts — and pays
 the upload — once).
@@ -246,8 +245,8 @@ def check_verified(directory: str, *, wait: bool = True) -> bool:
 
 def read_meta(directory: str) -> state_mod.StateMeta:
     """Read just the snapshot's :class:`StateMeta` — O(manifest), no array
-    bytes touched. The fabric gateway uses this to learn kmer size and
-    bucket geometry without ever holding the index itself. Shard-set
+    bytes touched: the kmer size and bucket geometry without holding the
+    index itself. Shard-set
     snapshots (see :mod:`repro.index.shards`) answer with the FULL
     unsharded meta from their CRC-checked set manifest."""
     if not os.path.exists(os.path.join(directory, MANIFEST)):
@@ -280,8 +279,8 @@ def load(directory: str, *, mmap: bool = True,
     checksum pass: ``"eager"`` checks every array before returning (reads
     all bytes — the default, and what a cold trust boundary wants),
     ``"lazy"`` starts a background thread and returns immediately
-    (:func:`check_verified` reports/raises its outcome — the fabric
-    worker boot path), ``"off"`` skips it. Legacy ``True``/``False`` map
+    (:func:`check_verified` reports/raises its outcome), ``"off"`` skips
+    it. Legacy ``True``/``False`` map
     to eager/off. Manifest shape/dtype specs are ALWAYS validated — one
     header read per file, so a truncated or reshaped array still fails
     loudly at open time in every mode. ``device=False`` keeps the word

@@ -7,14 +7,10 @@ the serve cell the dry-run lowers. By default the arch's full config (for
 8 GiB state on one chip); ``--smoke`` takes its small config. Only the
 genome length per file is cut, and it is printed as a reduction. The
 index is built through the ``idl_insert`` ingest backend and served
-through the ``idl_probe`` query backend. ``--procs N`` serves
-the same traffic through a :class:`ProcessFabric` instead: the index is
-snapshotted once and N worker processes mmap it behind one gateway.
-``--shards N`` partitions the index into N shard states, saves the
-shard-set snapshot, and serves through a :class:`ScatterGatherRouter` —
-each shard a worker process when ``--procs`` is also set — then runs the
-same recall check against the merged answers. The process tiers run on a
-CPU host only (a chip belongs to one process).
+through the ``idl_probe`` query backend. ``--shards N`` partitions the
+index into N shard states, saves the shard-set snapshot, and serves
+through a :class:`ScatterGatherRouter` whose shards are schedulers in
+this process, then runs the same recall check against the merged answers.
 """
 
 from __future__ import annotations
@@ -98,15 +94,11 @@ def main() -> None:
                     help="files in the archive (default: the config's)")
     ap.add_argument("--batch", type=int, default=64)
     ap.add_argument("--requests", type=int, default=5)
-    ap.add_argument("--procs", type=int, default=0, metavar="N",
-                    help="serve through a ProcessFabric of N mmap-booted "
-                         "worker processes instead of in-process")
     ap.add_argument("--shards", type=int, default=0, metavar="N",
                     help="partition the index into N shards and serve "
-                         "through a scatter-gather router (with --procs, "
-                         "each shard runs in its own worker process)")
+                         "through a scatter-gather router")
     ap.add_argument("--obs-dump", default=None, metavar="PATH",
-                    help="after serving, write the merged observability "
+                    help="after serving, write the observability "
                          "snapshot (metrics + traces, JSON) to PATH and "
                          "a Chrome trace_event file next to it")
     args = ap.parse_args()
@@ -146,24 +138,12 @@ def main() -> None:
         tmp = tempfile.TemporaryDirectory(prefix="serve_shards_")
         spec, parts = shards_mod.partition_state(eng, args.shards)
         shards_mod.save_shard_set(spec, parts, f"{tmp.name}/set")
-        router = ScatterGatherRouter(f"{tmp.name}/set", ScatterConfig(
-            procs=bool(args.procs), service=svc_cfg))
-        mode = ("worker processes" if args.procs
-                else "in-process schedulers")
+        router = ScatterGatherRouter(f"{tmp.name}/set",
+                                     ScatterConfig(service=svc_cfg))
         print(f"shards: {spec.n_shards} shards over the {spec.axis!r} "
-              f"axis, served by {mode} (set version "
+              f"axis, served by in-process schedulers (set version "
               f"{router.set_version})")
         search = router.search
-    elif args.procs:
-        from repro.index import store
-        from repro.serving import FabricConfig, ProcessFabric
-        tmp = tempfile.TemporaryDirectory(prefix="serve_fabric_")
-        snap = store.save(eng, f"{tmp.name}/snap")
-        fab = ProcessFabric(snap, FabricConfig(n_workers=args.procs,
-                                               service=svc_cfg))
-        print(f"fabric: {args.procs} worker processes, pids "
-              f"{sorted(fab.worker_pids().values())}")
-        search = fab.search
     else:
         svc = GeneSearchService(eng, svc_cfg)
         search = svc.search
@@ -185,21 +165,13 @@ def main() -> None:
           f"(batch={args.batch})")
     if args.obs_dump:
         from repro.obs import export as obs_export
-        if args.shards:
-            snap = router.obs_snapshot()   # fleet merge over shard procs
-        elif args.procs:
-            snap = fab.obs_snapshot()      # fleet merge over workers
-        else:
-            snap = obs_export.snapshot()   # one process = one registry
+        snap = obs_export.snapshot()   # one process = one registry
         paths = obs_export.dump(snap, args.obs_dump)
         print(f"obs: {len(snap.get('spans', ()))} spans, "
               f"{len(snap['metrics'].get('counters', {}))} counter "
               f"series -> {paths[0]} (+ {paths[1]})")
     if args.shards:
         router.close()
-        tmp.cleanup()
-    elif args.procs:
-        fab.close()
         tmp.cleanup()
 
 

@@ -1,18 +1,16 @@
-"""Observability plane: metrics registry, request tracing, fleet export.
+"""Observability plane: metrics registry, request tracing, export.
 
 Three modules threaded through every serving tier:
 
 - :mod:`repro.obs.metrics` — process-local thread-safe registry
   (counters / gauges / fixed-bucket log2 histograms) with pre-bound
-  handles so the hot path allocates nothing; ``snapshot()`` → plain dict,
-  ``merge()`` for cross-process aggregation.
-- :mod:`repro.obs.trace` — per-request spans minted at admission, riding
-  IPC frames across process boundaries, finished records in a bounded
-  ring, exported as JSON or Chrome ``trace_event``.
-- :mod:`repro.obs.export` — the one snapshot/merge/dump path shared by
-  the fabric gateway, scatter router and ``launch/serve.py --obs-dump``,
-  plus registry-backed views (``cache_stats_view``) that replace the
-  per-tier stats-dict merging.
+  handles so the hot path allocates nothing; ``snapshot()`` → plain dict.
+- :mod:`repro.obs.trace` — per-request spans minted at admission and
+  handed down the tiers, finished records in a bounded ring, exported as
+  JSON or Chrome ``trace_event``; and the pipeline's stages.
+- :mod:`repro.obs.export` — the one snapshot/dump path
+  (``launch/serve.py --obs-dump``), plus the registry-backed view
+  ``cache_stats_view``.
 
 ``set_enabled(False)`` flips metrics, tracing and stages to cheap no-ops
 (contract: obs-on serves bit-identically to obs-off). What the stages cost

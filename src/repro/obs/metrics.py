@@ -10,17 +10,15 @@ buckets (``bucket = bit_length(int(value))``, clamped to
 :data:`N_BUCKETS`), so observing a latency is an index increment into a
 pre-allocated list.
 
-``snapshot()`` renders the whole registry as a plain nested dict (JSON- and
-pickle-clean) and ``merge()`` folds any number of snapshots from other
-processes into one — the single cross-process aggregation path used by the
-fabric gateway and the scatter router (replacing their per-tier ad-hoc
-dict merging).
+``snapshot()`` renders the whole registry as a plain nested dict (JSON-
+clean); every serving tier runs in the one process, so one snapshot holds
+the whole fleet.
 
 Labels follow one vocabulary across the stack: ``tier`` (service /
-scheduler / router / fabric / scatter), ``engine``, ``scheme``, and
-``replica`` / ``shard`` / ``worker`` for fan-out tiers. Extra labels are
-allowed; they are sorted into a canonical ``k=v,k2=v2`` string at bind
-time, never on the hot path.
+scheduler / router / scatter), ``engine``, ``scheme``, and ``replica`` /
+``shard`` for fan-out tiers. Extra labels are allowed; they are sorted
+into a canonical ``k=v,k2=v2`` string at bind time, never on the hot
+path.
 
 Disabling (``set_enabled(False)``) turns every already-bound handle into a
 cheap no-op (one attribute load + branch per event) — used by the obs
@@ -32,7 +30,7 @@ from __future__ import annotations
 import os
 import threading
 import time
-from typing import Dict, Iterable, List, Mapping, Optional, Tuple
+from typing import Dict, Mapping, Optional, Tuple
 
 import numpy as np
 
@@ -240,8 +238,7 @@ class Registry:
         """Plain nested dict of everything the process has recorded:
         ``{"pid", "utc", "counters": {name: {labelkey: value}}, "gauges":
         {...}, "hists": {name: {labelkey: {count, sum, min, max,
-        buckets}}}}``. JSON- and pickle-clean; this is the unit the fleet
-        merge operates on."""
+        buckets}}}}``. JSON-clean."""
         with self._lock:
             counters = list(self._counters.items())
             gauges = list(self._gauges.items())
@@ -275,54 +272,6 @@ class Registry:
                            + list(self._hists.values()))
         for h in instruments:
             h._reset()
-
-
-def merge(snapshots: Iterable[dict]) -> dict:
-    """Fold process snapshots into one fleet snapshot: counters and
-    histogram buckets/count/sum SUM per (name, labelkey); histogram
-    min/max take the extrema; gauges are last-write-wins per (name,
-    labelkey) — fan-out tiers keep gauges distinct with ``pid=`` /
-    ``worker=`` / ``shard=`` labels so nothing collides. This is the one
-    cross-process aggregation path (gateway and scatter router both call
-    it)."""
-    out: dict = {"pid": os.getpid(),
-                 "utc": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
-                 "merged_from": 0,
-                 "counters": {}, "gauges": {}, "hists": {}}
-    for snap in snapshots:
-        if not snap:
-            continue
-        # provenance: leaf snapshots count 1, already-merged ones carry
-        # their own process count forward
-        prior = int(snap.get("merged_from", 0) or 0)
-        out["merged_from"] += prior if prior else 1
-        for name, series in snap.get("counters", {}).items():
-            dst = out["counters"].setdefault(name, {})
-            for lk, v in series.items():
-                dst[lk] = dst.get(lk, 0.0) + v
-        for name, series in snap.get("gauges", {}).items():
-            dst = out["gauges"].setdefault(name, {})
-            for lk, v in series.items():
-                dst[lk] = v
-        for name, series in snap.get("hists", {}).items():
-            dst = out["hists"].setdefault(name, {})
-            for lk, h in series.items():
-                cur = dst.get(lk)
-                if cur is None:
-                    dst[lk] = {"count": h["count"], "sum": h["sum"],
-                               "min": h["min"], "max": h["max"],
-                               "buckets": list(h["buckets"])}
-                else:
-                    cur["count"] += h["count"]
-                    cur["sum"] += h["sum"]
-                    if h["count"]:
-                        cur["min"] = (min(cur["min"], h["min"])
-                                      if cur["count"] != h["count"]
-                                      else h["min"])
-                        cur["max"] = max(cur["max"], h["max"])
-                    for i, b in enumerate(h["buckets"]):
-                        cur["buckets"][i] += b
-    return out
 
 
 def counter_total(snapshot: dict, name: str,

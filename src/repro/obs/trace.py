@@ -1,14 +1,12 @@
-"""Per-request tracing: spans, cross-process trace context, ring buffer.
+"""Per-request tracing: spans, trace context, ring buffer.
 
-A trace id is minted once at admission (service/scheduler submit, fabric
-gateway, scatter router) and follows the request everywhere — including
-across process boundaries: the gateway puts ``(trace_id, parent_span_id)``
-on the :class:`repro.serving.ipc.Request` frame, the worker opens child
-spans under that parent, and the worker's finished spans ride back in the
-obs snapshot so the gateway can stitch one tree out of many processes.
+A trace id is minted once at admission (service/scheduler submit, replica
+router, scatter router) and follows the request through the tiers: a
+tier hands ``(trace_id, parent_span_id)`` to the scheduler it dispatches
+to, which opens the request's pipeline spans under that parent.
 
 Span ids are ``"<pid hex>.<seq hex>"`` strings, so ids minted in different
-processes can never collide and a stitched tree needs no renumbering.
+processes can never collide.
 Timing uses the monotonic clock for durations (immune to wall-clock
 steps); each record also carries an epoch-anchored start (monotonic offset
 re-based once at import) so spans from one host line up on a shared
@@ -22,8 +20,8 @@ Hot-path discipline: the batch pipeline does not build Span objects per
 request mid-flight — it stamps monotonic times it mostly already takes,
 and emits finished records in one pass at finalize (:meth:`Tracer.emit`).
 An open :class:`Span` object is only held where someone must be able to
-*close it with an error later* (gateway-side dispatch spans, so a worker
-death closes them instead of leaking them).
+*close it with an error later* (a router's root span, closed when its
+gathered future resolves or fails).
 
 Stages (:class:`stage`) are the other half: batch-level intervals of the
 serving pipeline and the query planner's dispatch, timed where the work
@@ -48,7 +46,7 @@ from jax import profiler as jax_profiler
 
 from repro.obs import metrics as obs_metrics
 
-# Trace context as it rides an IPC frame: (trace_id, parent_span_id).
+# Trace context handed to a child tier: (trace_id, parent_span_id).
 TraceContext = Tuple[str, str]
 
 # Re-based once: epoch seconds at monotonic zero, so monotonic stamps
@@ -66,8 +64,8 @@ def mono_to_epoch(t_mono: float) -> float:
 class Span:
     """An OPEN span. Created via :meth:`Tracer.start`; finished with
     :meth:`end` (ok) or :meth:`end` with ``status='error'``. The tracer
-    tracks open spans so an owner (gateway) can error-close everything a
-    dead worker left behind."""
+    tracks open spans so :func:`repro.obs.reset` can error-close whatever
+    is still open."""
 
     __slots__ = ("tracer", "name", "trace_id", "span_id", "parent_id",
                  "attrs", "t0", "_done")
@@ -85,8 +83,7 @@ class Span:
         self._done = False
 
     def context(self) -> TraceContext:
-        """The ``(trace_id, span_id)`` pair a child — possibly in another
-        process — opens under."""
+        """The ``(trace_id, span_id)`` pair a child opens under."""
         return (self.trace_id, self.span_id)
 
     def end(self, status: str = "ok", **attrs: object) -> None:
@@ -162,8 +159,7 @@ class Tracer:
     def start(self, name: str, trace: Optional[TraceContext] = None,
               **attrs: object) -> Span:
         """Open a span. ``trace=None`` mints a fresh trace id (admission);
-        otherwise the span is a child of ``trace = (trace_id, parent)`` —
-        which may have been minted in another process."""
+        otherwise the span is a child of ``trace = (trace_id, parent)``."""
         if trace is None:
             trace_id, parent = self.mint_trace(), None
         else:
@@ -187,8 +183,7 @@ class Tracer:
 
     def close_open_spans(self, status: str = "error",
                          **attrs: object) -> int:
-        """Error-close every still-open span (gateway shutdown, or a
-        worker whose process died taking its in-flight work). Returns how
+        """Error-close every still-open span (an obs reset). Returns how
         many were closed."""
         with self._lock:
             orphans = list(self._open.values())
@@ -245,16 +240,6 @@ class Tracer:
             for name, sfx, ta_e, dur in shared:
                 append((trace_id, root + sfx, root, name,
                         pid, ta_e, dur, status, None))
-
-    def ingest(self, records: List[dict]) -> None:
-        """Fold finished records from ANOTHER tracer (a worker's snapshot,
-        shipped over IPC) into this ring — the stitching half of
-        cross-process tracing. Records already carry their origin pid."""
-        append = self._ring.append
-        for r in records:
-            append((r["trace"], r["span"], r["parent"], r["name"],
-                    r["pid"], r["t0"], r["dur"], r["status"],
-                    r.get("attrs")))
 
     # -- export ----------------------------------------------------------
     def records(self) -> List[dict]:

@@ -10,20 +10,21 @@ pluggable routing, hot snapshot swap under traffic) and
 driven by the recorded batch telemetry). :mod:`repro.serving.live` adds
 the write path: ``LiveGeneSearchService`` / ``LiveReplicaRouter`` serve a
 :class:`repro.index.lsm.LiveIndex` (base + delta) with background
-compaction. :mod:`repro.serving.genesearch` keeps the serve-geometry
+compaction. :mod:`repro.serving.scatter` fans each request over the
+shards of a sharded archive and merges the partials exactly. Every fleet
+(replicas, shards, the live router) runs in the process that owns the
+devices. :mod:`repro.serving.genesearch` keeps the serve-geometry
 config + plan helpers; its removed v1 bodies are call-time ImportError
 stubs.
 """
 
-from repro.serving import autoscale, fabric, genesearch, ipc, kmer_cache, \
-    live, router, scatter, scheduler, service
+from repro.serving import autoscale, genesearch, kmer_cache, live, router, \
+    scatter, scheduler, service
 from repro.serving.autoscale import (
     AdmissionPolicy,
     AutoscaleConfig,
     ReplicaAutoscaler,
 )
-from repro.serving.fabric import FabricConfig, FabricError, ProcessFabric, \
-    WorkerLost
 from repro.serving.kmer_cache import KmerCache, KmerCacheConfig, \
     merge_cache_stats, pack_codes
 from repro.serving.live import Compactor, LiveGeneSearchService, \
@@ -48,15 +49,12 @@ __all__ = [
     "BatchStats",
     "ClusterStats",
     "Compactor",
-    "FabricConfig",
-    "FabricError",
     "GeneSearchService",
     "InsertAck",
     "KmerCache",
     "KmerCacheConfig",
     "LiveGeneSearchService",
     "LiveReplicaRouter",
-    "ProcessFabric",
     "ReplicaAutoscaler",
     "ReplicaRouter",
     "RouterConfig",
@@ -70,11 +68,8 @@ __all__ = [
     "ServiceConfig",
     "ShardDeadError",
     "ShardSearchService",
-    "WorkerLost",
     "autoscale",
-    "fabric",
     "genesearch",
-    "ipc",
     "kmer_cache",
     "live",
     "merge_cache_stats",

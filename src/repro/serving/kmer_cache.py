@@ -123,8 +123,8 @@ def pack_codes(reads: np.ndarray, k: int) -> np.ndarray:
 
 @dataclasses.dataclass(frozen=True)
 class KmerCacheConfig:
-    """Knobs of the serving membership cache (static, picklable — rides
-    ``ServiceConfig`` across the fabric's process boundary)."""
+    """Knobs of the serving membership cache (static; rides
+    ``ServiceConfig`` to every replica)."""
 
     capacity: int = 1 << 16   # max cached kmers (least-recently-hit beyond)
 
@@ -327,8 +327,8 @@ class KmerCache:
         return self.hits / n if n else 0.0
 
     def stats(self) -> Dict[str, float]:
-        """One picklable dict — the shape ClusterStats scrapers, the
-        fabric's ``stats`` reply and the benches all share."""
+        """One plain dict — the shape ClusterStats scrapers, the routers
+        and the benches all share."""
         return {
             "hits": self.hits,
             "misses": self.misses,
@@ -343,11 +343,10 @@ class KmerCache:
 
 def merge_cache_stats(parts: Iterable[Optional[Dict[str, float]]]
                       ) -> Optional[Dict[str, float]]:
-    """Aggregate per-replica/per-worker ``KmerCache.stats()`` dicts.
+    """Aggregate per-replica ``KmerCache.stats()`` dicts.
 
     None entries (cache-less members) are skipped; returns None when no
-    member carries a cache — the routers' and the fabric gateway's
-    fleet-wide hit-rate view.
+    member carries a cache — the routers' fleet-wide hit-rate view.
     """
     merged: Optional[Dict[str, float]] = None
     for part in parts:

@@ -88,16 +88,15 @@ def _close_span_on_acks(span, futures: Sequence[Future]) -> None:
 
 
 class RoutingPolicy:
-    """The routing decision itself, factored out of the router so the
-    in-process replica fleet and the cross-process fabric gateway
-    (:mod:`repro.serving.fabric`) spread load with ONE policy core.
+    """The routing decision itself, the policy core of
+    :class:`ReplicaRouter` (and so of the live router built on it).
 
     ``pick(members, bucket, load)`` chooses among the ordered serving
     members (anything with a stable integer ``.id``); ``load`` maps a
     member to its outstanding-work figure (used by ``least_outstanding``).
     Policy state (the round-robin cursor, the bucket->member affinity
     map) lives here. Not thread-safe on its own — callers hold their
-    fleet lock across the pick, exactly as the router always did.
+    fleet lock across the pick.
     """
 
     def __init__(self, policy: str):
@@ -118,7 +117,7 @@ class RoutingPolicy:
             return min(members, key=load)
         # bucket_affinity: sticky bucket -> member map, assigned round-
         # robin on first sight so load still spreads; remapped only if
-        # the pinned member was decommissioned (or crashed, in the fabric)
+        # the pinned member was decommissioned
         by_id = {m.id: m for m in members}
         mid = self._affinity.get(bucket)
         if mid is None or mid not in by_id:
@@ -274,10 +273,8 @@ class ReplicaRouter:
 
     def obs_snapshot(self) -> dict:
         """Full process-local obs snapshot (metrics + finished spans).
-        For the in-process fleet every replica already feeds the one
-        process registry, so no per-replica merge is needed; the
-        cross-process tiers (fabric, scatter) ship this same shape over
-        IPC and fold with :func:`repro.obs.export.merge`."""
+        Every replica feeds the one process registry, so no per-replica
+        merge is needed."""
         return obs_export.snapshot()
 
     # -- routing ------------------------------------------------------------

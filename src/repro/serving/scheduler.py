@@ -142,8 +142,8 @@ class _Pending:
     n_kmers: int
     future: Future
     t_enq: float
-    # (trace_id, parent_span_id) minted at admission — locally, or in the
-    # gateway process when the request came over an IPC frame
+    # (trace_id, parent_span_id) minted at admission — here, or by the
+    # router that dispatched the request
     trace: Optional[Tuple[str, Optional[str]]] = None
 
 
@@ -241,7 +241,7 @@ class AsyncScheduler:
         """Enqueue one read; returns a Future resolving to SearchResult.
 
         ``trace`` parents this request's spans under an admission span
-        minted elsewhere (the fabric gateway / scatter router); None
+        minted elsewhere (the replica or scatter router); None
         mints a fresh trace id here.
         """
         req, n_kmers = self._svc._normalize(request)

@@ -67,9 +67,9 @@ def next_pow2(n: int) -> int:
 def bucket_for(n_kmers: int, min_bucket_kmers: int = 32) -> int:
     """The pow2 kmer bucket a request with ``n_kmers`` kmers lands in.
 
-    Module-level so admission-owning layers WITHOUT an index — the
-    process-fabric gateway routes on ``StateMeta`` alone — bucket with
-    the exact geometry the worker services compile for.
+    Module-level so a layer that answers without a service of its own —
+    the scatter router's merge — buckets with the exact geometry the
+    shard services compile for.
     """
     return max(next_pow2(n_kmers), min_bucket_kmers)
 
@@ -114,9 +114,9 @@ def normalize_request(request: Union[SearchRequest, np.ndarray], k: int
                       ) -> Tuple[SearchRequest, int]:
     """Shared admission validation: ``(request, n_kmers)`` or raise.
 
-    The ONE place a read becomes a typed request — used by the in-process
-    service and the fabric gateway alike, so a malformed read is rejected
-    with the same message at either boundary.
+    The ONE place a read becomes a typed request — used by the service
+    and the scatter router alike, so a malformed read is rejected with the
+    same message at either boundary.
     """
     if not isinstance(request, SearchRequest):
         request = SearchRequest(read=np.asarray(request))
@@ -177,8 +177,8 @@ def emit_request_spans(entries, *, bucket: int, t0: float, t_asm: float,
 
     ``entries`` is ``[(trace_ctx, t_enq, request_id), ...]`` where
     ``trace_ctx`` is ``(trace_id, parent_span_id_or_None)`` minted at
-    admission (possibly in another process — the fabric gateway's ctx
-    rides the IPC frame). Each request gets a root ``request`` span with
+    admission (by a router, when one dispatched the request). Each
+    request gets a root ``request`` span with
     ``queue_wait → assemble → execute → finalize`` children; the batch
     stages share their (batch-level) boundaries, the queue wait is the
     request's own. The whole batch is ONE

@@ -1,13 +1,10 @@
 """Observability plane: registry semantics, tracer semantics, export
-round-trips, and the in-process span chains the serving tiers emit.
-
-Cross-process stitching (gateway <-> worker over IPC) is asserted in
-``test_fabric.py``; this file covers everything that doesn't need a
-spawned interpreter: the metrics registry (pre-bound handles, log2
-bucketing, snapshot/merge), the tracer (ids, parenting, idempotent
-closure, the bounded ring), the export module (dump round-trip, the
-registry-backed cache view), and the query/insert span trees emitted by
-the sync service, the async scheduler and the live replica router.
+round-trips, and the span chains the serving tiers emit: the metrics
+registry (pre-bound handles, log2 bucketing, snapshots), the tracer
+(ids, parenting, idempotent closure, the bounded ring), the export module
+(dump round-trip, the registry-backed cache view), and the query/insert
+span trees emitted by the sync service, the async scheduler and the live
+replica router.
 """
 
 import json
@@ -179,33 +176,6 @@ class TestMergeAndViews:
         build(reg)
         return reg.snapshot()
 
-    def test_merge_sums_counters_and_hists_lastwins_gauges(self):
-        def one(reg):
-            reg.counter("c", worker=0).inc(2)
-            reg.gauge("g").set(1)
-            h = reg.histogram("h")
-            h.observe(1)
-            h.observe(100)
-
-        def two(reg):
-            reg.counter("c", worker=0).inc(3)
-            reg.counter("c", worker=1).inc(10)
-            reg.gauge("g").set(9)
-            reg.histogram("h").observe(4)
-
-        merged = obs_metrics.merge([self._snap(one), self._snap(two)])
-        assert merged["merged_from"] == 2
-        assert merged["counters"]["c"]["worker=0"] == 5.0
-        assert merged["counters"]["c"]["worker=1"] == 10.0
-        assert merged["gauges"]["g"][""] == 9.0
-        h = merged["hists"]["h"][""]
-        assert h["count"] == 3
-        assert h["min"] == 1.0 and h["max"] == 100.0
-        assert sum(h["buckets"]) == 3
-        # merging merged snapshots accumulates provenance
-        again = obs_metrics.merge([merged, self._snap(one)])
-        assert again["merged_from"] == 3
-
     def test_counter_total_filters_on_labels(self):
         def build(reg):
             reg.counter("n", scheme="idl", op="query").inc(4)
@@ -325,26 +295,6 @@ class TestTracer:
         names = [r["name"] for r in trc.records()]
         assert names == ["s6", "s7", "s8", "s9"]  # oldest evicted
 
-    def test_ingest_and_exports(self):
-        worker = obs_trace.Tracer()
-        gateway = obs_trace.Tracer()
-        root = gateway.start("request")
-        w = worker.start("request", trace=root.context())
-        w.end()
-        root.end()
-        gateway.ingest(worker.records())          # stitch worker records
-        snap = obs_export.snapshot(registry=obs_metrics.Registry(),
-                                   tracer=gateway)
-        assert len(snap["spans"]) == 2
-        (spans,) = obs_export.traces_of(snap).values()    # ONE trace id
-        assert {s["span"] for s in spans} == {root.span_id, w.span_id}
-        chrome = obs_export.chrome_events(snap)
-        assert len(chrome["traceEvents"]) == 2
-        ev = chrome["traceEvents"][0]
-        assert ev["ph"] == "X" and ev["tid"] == root.trace_id
-        assert ev["args"]["span"] in (root.span_id, w.span_id)
-
-
 # ---------------------------------------------------------------------------
 # export module
 # ---------------------------------------------------------------------------
@@ -358,18 +308,6 @@ class TestExport:
         trc.start("child", trace=span.context()).end()
         span.end()
         return obs_export.snapshot(registry=reg, tracer=trc)
-
-    def test_snapshot_merge_traces_of(self):
-        a, b = self._private(), self._private()
-        merged = obs_export.merge([a, b, None, {}])
-        assert merged["metrics"]["counters"]["c"][""] == 4.0
-        assert len(merged["spans"]) == 4
-        t0s = [r["t0"] for r in merged["spans"]]
-        assert t0s == sorted(t0s)
-        traces = obs_export.traces_of(merged)
-        assert len(traces) == 2                   # distinct trace ids kept
-        for recs in traces.values():
-            assert {r["name"] for r in recs} == {"request", "child"}
 
     def test_dump_round_trip(self, tmp_path):
         snap = self._private()

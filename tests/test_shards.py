@@ -1,22 +1,15 @@
 """Sharded-archive acceptance: partition/join round-trips bit-exactly,
 the parallel sharded build equals the serial archive builder, the
 scatter-gather router's answers are bit-identical to one service over
-the unsharded index — across every engine x scheme x theta, including
-through real shard worker processes — and shard death keeps the exact
-semantics: row-probe answers name their ``missing_files``, bit-probe
-death fails loud (``ShardDeadError``), and zero futures are ever
-dropped.
-
-Proc-mode routers spawn real interpreters (each re-imports jax), so
-those tests keep fleets small (2 shards) and only cover one engine per
-partition axis — the in-process matrix already proves the merge math
-for all four engines.
+the unsharded index — across every engine x scheme x theta — and shard
+death keeps the exact semantics: row-probe answers name their
+``missing_files``, bit-probe death fails loud (``ShardDeadError``), and
+zero futures are ever dropped.
 """
 
 import json
 import os
 import shutil
-import time
 
 import numpy as np
 import pytest
@@ -215,6 +208,35 @@ class TestScatterSurface:
                 assert not np.asarray(r.matches)[list(lost)].any()
             assert router.live_shards() == [0]
 
+    def test_inprocess_kill_bit_probe_fails_loud(self, built, queries):
+        """A rambo shard taken down under a stream: what it already held
+        resolves exactly, every later gather raises ShardDeadError — never
+        a silently inflated answer, never a dropped future."""
+        _, _, set_dir, full = built("rambo", "idl")
+        svc_cfg = service_mod.ServiceConfig(theta=0.6, max_batch=4)
+        want = service_mod.GeneSearchService(full, svc_cfg).search(queries)
+        with ScatterGatherRouter(
+                set_dir, ScatterConfig(service=svc_cfg)) as router:
+            futures = [router.submit(q) for q in queries]
+            router.kill_shard(0)
+            futures += [router.submit(q) for q in queries]
+            outcomes = {"ok": 0, "dead": 0}
+            for i, f in enumerate(futures):
+                try:
+                    r = f.result(timeout=120)
+                    np.testing.assert_array_equal(
+                        np.asarray(r.matches),
+                        np.asarray(want[i % len(want)].matches))
+                    outcomes["ok"] += 1
+                except ShardDeadError:
+                    outcomes["dead"] += 1
+            # zero dropped: the shard drained what it held before it
+            # went down, and every later gather failed loud
+            assert outcomes == {"ok": len(queries), "dead": len(queries)}
+            assert router.live_shards() == [1]
+            with pytest.raises(ShardDeadError, match="failing loud"):
+                router.submit(queries[0]).result(timeout=120)
+
 
 # ---------------------------------------------------------------------------
 # Persistence: the CRC-checked shard-set manifest fails by name.
@@ -282,90 +304,3 @@ class TestShardSetPersistence:
             shards.plan_shards(spec.meta, 1000)
         with pytest.raises(shards.ShardSetError, match="want 1 <="):
             shards.plan_shards(spec.meta, 0)
-
-
-# ---------------------------------------------------------------------------
-# Proc-mode: real shard worker processes, one test per partition axis.
-# ---------------------------------------------------------------------------
-
-def _proc_router(set_dir, theta=1.0):
-    return ScatterGatherRouter(set_dir, ScatterConfig(
-        procs=True,
-        service=service_mod.ServiceConfig(theta=theta, max_batch=4)))
-
-
-class TestProcShards:
-
-    def test_row_probe_procs_parity_then_kill(self, built, queries):
-        """2 bit-sliced shard processes: answers == unsharded oracle;
-        kill -9 one shard mid-stream and every future still resolves,
-        late answers naming the dead shard's files as missing."""
-        spec, _, set_dir, full = built("bitsliced", "idl")
-        oracle = service_mod.GeneSearchService(
-            full, service_mod.ServiceConfig(max_batch=4))
-        want = oracle.search(queries)
-        with _proc_router(set_dir) as router:
-            got = router.search(queries)
-            for w, g in zip(want, got):
-                np.testing.assert_array_equal(np.asarray(g.matches),
-                                              np.asarray(w.matches))
-                assert g.file_ids == w.file_ids
-                assert g.missing_files == ()
-            stream = [queries[i % len(queries)] for i in range(18)]
-            futures = [router.submit(q) for q in stream]
-            router.kill_shard(1)
-            results = [f.result(timeout=120) for f in futures]
-            lost = shards.shard_files(spec, 1)
-            kept = sorted(set(range(N_FILES)) - set(lost))
-            for w, r in zip((want[i % len(want)] for i in range(18)),
-                            results):
-                wm, gm = np.asarray(w.matches), np.asarray(r.matches)
-                if r.missing_files:     # answered after the kill landed
-                    assert r.missing_files == lost
-                    assert not gm[list(lost)].any()
-                np.testing.assert_array_equal(gm[kept], wm[kept])
-            # the surviving shard keeps serving honest partial answers
-            deadline = time.monotonic() + 30
-            while len(router.live_shards()) > 1 \
-                    and time.monotonic() < deadline:
-                time.sleep(0.05)
-            assert router.live_shards() == [0]
-            late = router.search(queries)
-            for w, r in zip(want, late):
-                assert r.missing_files == lost
-                np.testing.assert_array_equal(
-                    np.asarray(r.matches)[kept],
-                    np.asarray(w.matches)[kept])
-
-    def test_bit_probe_procs_parity_then_kill_fails_loud(self, built,
-                                                         queries):
-        """2 rambo shard processes: answers == unsharded oracle at
-        theta=0.6; kill -9 one shard and affected futures raise
-        ShardDeadError — never a silently-inflated answer, never a
-        dropped future."""
-        _, _, set_dir, full = built("rambo", "idl")
-        oracle = service_mod.GeneSearchService(
-            full, service_mod.ServiceConfig(theta=0.6, max_batch=4))
-        want = oracle.search(queries)
-        with _proc_router(set_dir, theta=0.6) as router:
-            got = router.search(queries)
-            for w, g in zip(want, got):
-                np.testing.assert_array_equal(np.asarray(g.matches),
-                                              np.asarray(w.matches))
-                assert g.file_ids == w.file_ids
-            stream = [queries[i % len(queries)] for i in range(18)]
-            futures = [router.submit(q) for q in stream]
-            router.kill_shard(0)
-            outcomes = {"ok": 0, "dead": 0}
-            for i, f in enumerate(futures):
-                try:
-                    r = f.result(timeout=120)
-                    np.testing.assert_array_equal(
-                        np.asarray(r.matches),
-                        np.asarray(want[i % len(want)].matches))
-                    outcomes["ok"] += 1
-                except ShardDeadError:
-                    outcomes["dead"] += 1
-            assert sum(outcomes.values()) == len(futures)   # zero dropped
-            with pytest.raises(ShardDeadError, match="failing loud"):
-                router.submit(queries[0]).result(timeout=120)

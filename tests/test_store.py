@@ -214,7 +214,7 @@ class TestRejection:
 
 
 class TestVerifyModes:
-    """The eager/lazy/off verification contract the fabric boot path uses."""
+    """The eager/lazy/off verification contract of ``store.load``."""
 
     @pytest.fixture
     def snap(self, tmp_path, reads):
